@@ -209,19 +209,28 @@ class PartitionStats:
         maxs = np.full((P, C), -np.inf)
         nulls = np.zeros((P, C), dtype=np.int64)
         rows = np.diff(part_bounds).astype(np.int64)
+        # One segmented reduction per column: each non-empty partition's
+        # rows are [start, next non-empty start), so reduceat over the
+        # non-empty starts never sees an empty segment.  Null rows enter
+        # as +inf / -inf, so an all-null partition keeps the empty
+        # interval sentinel.
+        filled = rows > 0
+        starts = np.asarray(part_bounds[:-1], dtype=np.int64)[filled]
+        end = int(part_bounds[-1])
         for ci, col in enumerate(columns):
-            vals = encoded[col.name]
+            if not starts.size:
+                break
+            vals = np.asarray(encoded[col.name][:end])
             nmask = null_masks.get(col.name)
-            for p in range(P):
-                s, e = part_bounds[p], part_bounds[p + 1]
-                v = vals[s:e]
-                if nmask is not None:
-                    m = nmask[s:e]
-                    nulls[p, ci] = int(m.sum())
-                    v = v[~m]
-                if v.size:
-                    mins[p, ci] = v.min()
-                    maxs[p, ci] = v.max()
+            lo_vals = hi_vals = vals
+            if nmask is not None:
+                nmask = np.asarray(nmask[:end], dtype=bool)
+                nulls[filled, ci] = np.add.reduceat(
+                    nmask.astype(np.int64), starts)
+                lo_vals = np.where(nmask, np.inf, vals)
+                hi_vals = np.where(nmask, -np.inf, vals)
+            mins[filled, ci] = np.minimum.reduceat(lo_vals, starts)
+            maxs[filled, ci] = np.maximum.reduceat(hi_vals, starts)
         return PartitionStats(list(columns), mins, maxs, nulls, rows)
 
 

@@ -18,8 +18,9 @@ bound, so padding never produces a hit.
 ``join_overlap_batched`` is the workload-scale variant: Q queries' distinct
 lists (packed into power-of-two buckets, +inf padded) against the table's
 *resident* join-key plane (core/device_stats.py) in one launch — queries on
-the sublane dim like minmax_prune_batched, so a table group's JOIN pruning
-costs one launch regardless of the number of queries.
+the sublane dim like minmax_prune_batched and each query's keys on the
+lanes, so a table group's JOIN pruning costs one launch regardless of the
+number of queries.
 """
 
 from __future__ import annotations
@@ -33,6 +34,7 @@ from jax.experimental import pallas as pl
 BLOCK_P = 1024
 BLOCK_D = 2048
 BLOCK_QB = 8     # queries per tile in the batched kernel (f32 sublane height)
+LANES = 128      # lane width: the batched kernel walks keys a lane chunk at a time
 
 
 def _join_overlap_kernel(pmin_ref, pmax_ref, dist_ref, hit_ref):
@@ -48,25 +50,35 @@ def _join_overlap_kernel(pmin_ref, pmax_ref, dist_ref, hit_ref):
 
 
 def _join_overlap_batched_kernel(dist_ref, pmin_ref, pmax_ref, hit_ref):
-    Db = dist_ref.shape[0]
-    BQ = dist_ref.shape[1]
-    pmin = pmin_ref[0, :]          # [BP]
-    pmax = pmax_ref[0, :]          # [BP]
-    BP = pmin.shape[0]
+    BQ, Db = dist_ref.shape
+    pmin = pmin_ref[...]           # [1, BP]
+    pmax = pmax_ref[...]           # [1, BP]
+    width = min(Db, LANES)
 
-    def body(d, hit):
-        dk = dist_ref[d, :][:, None]                       # [BQ, 1]
-        inside = (dk >= pmin[None, :]) & (dk <= pmax[None, :])
-        return hit | inside.astype(jnp.int32)
+    def fold(keys, hit):           # keys [BQ, width]: one lane per build key
+        # Static lane slices: Mosaic has no dynamic lane-dim indexing, so
+        # keys are walked a 128-lane chunk at a time, unrolled within it.
+        for i in range(width):
+            dk = keys[:, i:i + 1]                          # [BQ, 1]
+            hit = hit | ((dk >= pmin) & (dk <= pmax)).astype(jnp.int32)
+        return hit
 
-    hit = jax.lax.fori_loop(0, Db, body, jnp.zeros((BQ, BP), jnp.int32))
+    hit = jnp.zeros((BQ, pmin.shape[1]), jnp.int32)
+    if Db <= LANES:
+        hit = fold(dist_ref[...], hit)
+    else:
+        def body(c, hit):
+            off = pl.multiple_of(c * LANES, LANES)
+            return fold(dist_ref[:, pl.ds(off, LANES)], hit)
+
+        hit = jax.lax.fori_loop(0, Db // LANES, body, hit)
     hit_ref[...] = hit
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def join_overlap_batched(
-    dist: jax.Array,     # [Db, Q] f32 distinct build keys per query,
-                         #         +inf padded (keys on the sublane dim)
+    dist: jax.Array,     # [Q, Db] f32 distinct build keys per query,
+                         #         +inf padded (keys on the lane dim)
     pmin: jax.Array,     # [P] f32 resident probe key-column minima (widened,
                          #         FINITE — core.device_stats clamps ±inf)
     pmax: jax.Array,     # [P] f32 resident probe key-column maxima (widened)
@@ -82,13 +94,20 @@ def join_overlap_batched(
     ``+inf <= pmax`` is always False, so a pad key never produces a hit
     (and an all-pad query row yields an all-zero hit row, sliced off).
 
+    Layout: queries on the sublane dim (BLOCK_QB per tile), keys on the
+    lanes of the same tile — the key block spans the full Db dim, and Db
+    is a power of two, so a block is either the whole (< 128-lane) array
+    or whole 128-lane chunks, the only shapes Mosaic tiles.
+
     Returns hit [Q, P] int32 (0 -> partition is prunable for that query).
     """
-    Db, Q = dist.shape
+    Q, Db = dist.shape
     P = pmin.shape[0]
+    if Db > LANES and Db % LANES:
+        raise ValueError(f"Db={Db} must be <= {LANES} or a multiple of it")
     pad_q = (-Q) % BLOCK_QB
     if pad_q:
-        dist = jnp.pad(dist, ((0, 0), (0, pad_q)), constant_values=jnp.inf)
+        dist = jnp.pad(dist, ((0, pad_q), (0, 0)), constant_values=jnp.inf)
     pad_p = (-P) % BLOCK_P
     if pad_p:
         # Empty finite intervals, like minmax_prune_batched's P padding.
@@ -101,7 +120,7 @@ def join_overlap_batched(
         _join_overlap_batched_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((Db, BLOCK_QB), lambda i, j: (0, i)),
+            pl.BlockSpec((BLOCK_QB, Db), lambda i, j: (i, 0)),
             pl.BlockSpec((1, BLOCK_P), lambda i, j: (0, j)),
             pl.BlockSpec((1, BLOCK_P), lambda i, j: (0, j)),
         ],

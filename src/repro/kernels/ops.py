@@ -39,11 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as PSpec
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:              # pragma: no cover - very old jax
-    shard_map = None
-
 from ..core.device_stats import (TREE_MIN_GROUPS, DeviceStats,
                                  cast_bounds_f32, cast_stats_f32,
                                  round_down_f32, round_up_f32,
@@ -65,6 +60,24 @@ _REF_SLAB_ELEMS = 1 << 25
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def kernel_interpret(mode: str) -> bool:
+    """The ``interpret`` flag of a Pallas launch in ``mode``.
+
+    Only ``mode="interpret"`` interprets.  Any other mode that reaches a
+    kernel compiles it for the TPU, and without one it raises instead of
+    interpreting in silence: ``"auto"`` never gets here off the TPU (it
+    takes the jnp/numpy path), so this guards an explicit ``"pallas"``.
+    """
+    if mode == "interpret":
+        return True
+    if not _on_tpu():
+        raise RuntimeError(
+            f"mode={mode!r} compiles the Pallas kernels for a TPU, but the "
+            f"default backend is {jax.default_backend()!r}; use "
+            f"mode='interpret' to run them on this backend")
+    return False
 
 
 # ---------------------------------------------------------------------------
@@ -92,7 +105,7 @@ def mesh_shards(mesh, cap: int) -> int:
     this holds for every plane at least as wide as the mesh); otherwise 1
     — the launch simply stays unsharded, same math, one device.
     """
-    if mesh is None or shard_map is None:
+    if mesh is None:
         return 1
     if PLANE_AXIS not in getattr(mesh, "axis_names", ()):
         return 1
@@ -104,6 +117,12 @@ def _use_kernel(mode: str) -> bool:
     """Kernel vs jnp-oracle body inside a sharded launch — the same
     mode policy as the unsharded wrappers (``auto`` off-TPU -> oracle)."""
     return mode != "ref" and (mode != "auto" or _on_tpu())
+
+
+def _body_flags(mode: str) -> Tuple[bool, bool]:
+    """(use_kernel, interpret) for a sharded launch's per-shard body."""
+    use = _use_kernel(mode)
+    return use, use and kernel_interpret(mode)
 
 
 # Shard count the most recent batched launch on THIS thread actually
@@ -138,9 +157,9 @@ def _sharded_minmax(mesh, use_kernel: bool, interp: bool):
         return ref.minmax_prune_batched_ref(c, l, h, m, x, d)
 
     rep, sp = PSpec(), PSpec(None, PLANE_AXIS)
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(rep, rep, rep, sp, sp, sp),
-                             out_specs=sp, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(rep, rep, rep, sp, sp, sp),
+                                 out_specs=sp, check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -150,10 +169,10 @@ def _sharded_join(mesh, use_kernel: bool, interp: bool):
             return join_overlap_batched(d, a, b, interpret=interp)
         return ref.join_overlap_batched_ref(d, a, b)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(PSpec(), PSpec(PLANE_AXIS), PSpec(PLANE_AXIS)),
-        out_specs=PSpec(None, PLANE_AXIS), check_rep=False))
+        out_specs=PSpec(None, PLANE_AXIS), check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -164,10 +183,10 @@ def _sharded_bloom(mesh, use_kernel: bool, interp: bool, enum_pad: int):
                                        interpret=interp)
         return ref.bloom_probe_batched_ref(l, h, pm, w, enum_pad)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(PSpec(), PSpec(), PSpec(PLANE_AXIS), PSpec(PLANE_AXIS)),
-        out_specs=PSpec(None, PLANE_AXIS), check_rep=False))
+        out_specs=PSpec(None, PLANE_AXIS), check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -179,10 +198,10 @@ def _sharded_topk(mesh, use_kernel: bool, interp: bool, k: int):
             heap = ref.topk_init_batched_ref(pl, m, k)
         return heap[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(PSpec(PLANE_AXIS, None), PSpec(PLANE_AXIS, None)),
-        out_specs=PSpec(PLANE_AXIS, None, None), check_rep=False))
+        in_specs=(PSpec(PLANE_AXIS, None), PSpec(None, PLANE_AXIS)),
+        out_specs=PSpec(PLANE_AXIS, None, None), check_vma=False))
 
 
 def _pow2_at_least(n: int, floor: int = 1) -> int:
@@ -294,7 +313,7 @@ def prune_ranges_device(
         tv = ref.minmax_prune_ref(lo, hi, mins, maxs, nullable)
     else:
         tv = minmax_prune(lo, hi, mins, maxs, nullable,
-                          interpret=(mode == "interpret") or not _on_tpu())
+                          interpret=kernel_interpret(mode))
     tv = np.asarray(tv)
     if not full_safe:
         tv = np.minimum(tv, 1)   # inexact f32 bounds: FULL is not provable
@@ -380,8 +399,7 @@ def prune_ranges_batched_device(
                        # the unsharded path below slabs instead
     _note_shards(shards)
     if shards > 1:
-        fn = _sharded_minmax(mesh, _use_kernel(mode),
-                             (mode == "interpret") or not _on_tpu())
+        fn = _sharded_minmax(mesh, *_body_flags(mode))
         tv = np.asarray(fn(cids_d, lo_d, hi_d, mins, maxs, demote))
     elif mode == "ref" or (mode == "auto" and not _on_tpu()):
         slab = max(1024, _REF_SLAB_ELEMS // Qb)
@@ -400,7 +418,7 @@ def prune_ranges_batched_device(
     else:
         tv = np.asarray(minmax_prune_batched(
             cids_d, lo_d, hi_d, mins, maxs, demote,
-            interpret=(mode == "interpret") or not _on_tpu()))
+            interpret=kernel_interpret(mode)))
     tv = tv[:Q, :P].astype(np.int8)
     if not full_safe.all():
         tv[~full_safe] = np.minimum(tv[~full_safe], 1)
@@ -562,7 +580,12 @@ def prune_ranges_batched_tree(
     # everything; the density must price only the real rows.
     csurv = _coarse_survivors(cids[:Q], lo[:Q], hi[:Q], cmins, cmaxs)
     G2 = csurv.shape[1]
-    cdens = csurv.sum(axis=1).max() / G2
+    # Density over the coarse groups that hold live partitions: the
+    # capacity tail (up to half the plane after a power-of-two resize)
+    # never survives, and counting it would keep a whole-table predicate
+    # under the cutoff.
+    live_g2 = -(-P // (Pc // G2))
+    cdens = csurv.sum(axis=1).max() / max(live_g2, 1)
     if cdens > dense_cutoff:
         _note_tree(path="flat_dense", groups=G, coarse_density=float(cdens))
         return prune_ranges_batched_device(range_lists, dstats, mode,
@@ -826,7 +849,7 @@ def topk_boundary_device(
         # skip a block the f64 boundary would have kept
         b32 = jnp.asarray(round_down_f32(b_init))
         skip, heap = topk_boundary(rows_j, b32,
-                                   interpret=(mode == "interpret") or not _on_tpu())
+                                   interpret=kernel_interpret(mode))
     return np.asarray(skip), np.asarray(heap)
 
 
@@ -844,7 +867,7 @@ def join_overlap_device(
         hit = ref.join_overlap_ref(pmin, pmax, d)
     else:
         hit = join_overlap(pmin, pmax, d,
-                           interpret=(mode == "interpret") or not _on_tpu())
+                           interpret=kernel_interpret(mode))
     return np.asarray(hit)
 
 
@@ -855,18 +878,18 @@ def join_overlap_device(
 def pack_distinct(
     distinct_lists: Sequence[np.ndarray],
 ) -> np.ndarray:
-    """Pack per-query sorted distinct keys into the [Db, Qb] kernel layout.
+    """Pack per-query sorted distinct keys into the [Qb, Db] kernel layout.
 
     Db/Qb are power-of-two buckets (``d_bucket`` / ``q_bucket``); padding
-    is +inf — sorted last (the ref path binary-searches each column) and
+    is +inf — sorted last (the ref path binary-searches each row) and
     never inside a finite range (the kernel path compares directly).
     """
     Q = len(distinct_lists)
     Db = d_bucket(max((len(d) for d in distinct_lists), default=1))
     Qb = q_bucket(Q)
-    dist = np.full((Db, Qb), np.inf, dtype=np.float32)
+    dist = np.full((Qb, Db), np.inf, dtype=np.float32)
     for qi, d in enumerate(distinct_lists):
-        dist[: len(d), qi] = np.asarray(d, dtype=np.float32)
+        dist[qi, : len(d)] = np.asarray(d, dtype=np.float32)
     return dist
 
 
@@ -901,8 +924,7 @@ def join_overlap_batched_device(
         shards = 1     # keep the C-speed searchsorted fallback below
     _note_shards(shards)
     if shards > 1:
-        fn = _sharded_join(mesh, _use_kernel(mode),
-                           (mode == "interpret") or not _on_tpu())
+        fn = _sharded_join(mesh, *_body_flags(mode))
         hit = np.asarray(fn(jnp.asarray(pack_distinct(distinct_lists)),
                             pmin, pmax))
         return hit[:Q]
@@ -929,7 +951,7 @@ def join_overlap_batched_device(
     dist_d = jnp.asarray(pack_distinct(distinct_lists))
     hit = np.asarray(join_overlap_batched(
         dist_d, pmin, pmax,
-        interpret=(mode == "interpret") or not _on_tpu()))
+        interpret=kernel_interpret(mode)))
     return hit[:Q]
 
 
@@ -996,8 +1018,7 @@ def bloom_probe_batched_device(
     if shards > 1:
         lo, hi = pack_blooms(blooms)
         width_eff = jnp.where(width <= enum_limit, width, 0).astype(jnp.int32)
-        fn = _sharded_bloom(mesh, _use_kernel(mode),
-                            (mode == "interpret") or not _on_tpu(), eb)
+        fn = _sharded_bloom(mesh, *_body_flags(mode), eb)
         hit = np.asarray(fn(jnp.asarray(lo), jnp.asarray(hi),
                             pmin, width_eff))
         return hit[:Q]
@@ -1026,7 +1047,7 @@ def bloom_probe_batched_device(
     eb = enum_bucket(max(1, min(int(wmax), int(enum_limit))))
     hit = np.asarray(bloom_probe_batched(
         jnp.asarray(lo), jnp.asarray(hi), pmin, width_eff, enum_pad=eb,
-        interpret=(mode == "interpret") or not _on_tpu()))
+        interpret=kernel_interpret(mode)))
     return hit[:Q]
 
 
@@ -1064,9 +1085,8 @@ def topk_init_batched_device(
                        # gather below wins at fleet shapes
     _note_shards(shards)
     if shards > 1:
-        mask_d = jnp.asarray(mask.astype(np.float32).T)   # [Pp, Q]
-        fn = _sharded_topk(mesh, _use_kernel(mode),
-                           (mode == "interpret") or not _on_tpu(), k)
+        mask_d = jnp.asarray(mask.astype(np.float32))      # [Q, Pp]
+        fn = _sharded_topk(mesh, *_body_flags(mode), k)
         heaps = np.asarray(fn(plane, mask_d))             # [n, Q, k]
         # Rank-selection merge of the per-shard heaps: top-k is a pure
         # selection, so selecting k from the union of shard-local top-k
@@ -1089,8 +1109,7 @@ def topk_init_batched_device(
             top = np.sort(vals)[::-1]
             heap[qi, : top.size] = top
         return heap
-    mask_d = jnp.asarray(mask.astype(np.float32).T)   # [P, Q]
     heap = topk_init_batched(
-        plane, mask_d, k,
-        interpret=(mode == "interpret") or not _on_tpu())
+        plane, jnp.asarray(mask.astype(np.float32)), k,
+        interpret=kernel_interpret(mode))
     return np.asarray(heap)

@@ -212,16 +212,16 @@ def join_overlap_batched_ref(dist, pmin, pmax) -> jax.Array:
     """hit [Q, P] int32 for Q queries' distinct lists vs one key plane.
 
     Mirrors kernels/join_overlap.py::join_overlap_batched: ``dist`` is
-    [Db, Q] with each query's *sorted* distinct keys on the sublane dim,
-    padded with +inf — which sorts last and, with the plane clamped to
-    finite f32 (pmax <= f32max), can never land inside a range, so the
+    [Q, Db] with row q holding query q's *sorted* distinct keys, padded
+    with +inf — which sorts last and, with the plane clamped to finite
+    f32 (pmax <= f32max), can never land inside a range, so the
     searchsorted counts are untouched by padding."""
     def one(d):
         lo = jnp.searchsorted(d, pmin, side="left")
         hi = jnp.searchsorted(d, pmax, side="right")
         return (hi > lo).astype(jnp.int32)
 
-    return jax.vmap(one, in_axes=1)(dist)
+    return jax.vmap(one)(dist)
 
 
 def topk_init_batched_ref(plane, mask, k: int) -> jax.Array:
@@ -231,9 +231,10 @@ def topk_init_batched_ref(plane, mask, k: int) -> jax.Array:
     O(Q*P*K), so it serves as the small-shape test oracle.  The
     production no-Pallas fallback (ops.topk_init_batched_device) instead
     exploits mask sparsity with a per-query numpy gather + partition —
-    top-k is a pure selection, so both return identical values."""
-    Q = mask.shape[1]
-    vals = jnp.where(mask.T[:, :, None] > 0, plane[None, :, :], -jnp.inf)
+    top-k is a pure selection, so both return identical values.
+    ``mask`` is [Q, P], like the kernel's."""
+    Q = mask.shape[0]
+    vals = jnp.where(mask[:, :, None] > 0, plane[None, :, :], -jnp.inf)
     flat = vals.reshape(Q, -1)
     if flat.shape[1] < k:
         flat = jnp.pad(flat, ((0, 0), (0, k - flat.shape[1])),

@@ -93,77 +93,98 @@ def _topk_boundary_kernel(binit_ref, rows_ref, skip_ref, heap_ref, scratch):
 
 
 BLOCK_QI = 8     # queries per tile in the batched init kernel
-BLOCK_PI = 128   # partitions folded into the heaps per grid step
+BLOCK_PI = 512   # partitions folded into the heaps per grid step
 
 
-def _merge_topk_rows(heap: jax.Array, rows: jax.Array, k: int) -> jax.Array:
-    """Row-wise top-k merge: heap [BQ, k] desc + rows [BQ, m] -> [BQ, k].
+def _merge_sorted_rows(heap: jax.Array, rows: jax.Array, k: int) -> jax.Array:
+    """Row-wise top-k merge: heap [BQ, k] desc + rows [BQ, m] desc -> [BQ, k].
 
-    The batched analogue of ``_merge_topk``: rank selection via an
-    all-pairs comparison per query row, branch-free VPU work."""
-    cand = jnp.concatenate([heap, rows], axis=1)            # [BQ, n]
-    n = cand.shape[1]
-    ci = cand[:, :, None]                                   # value of i
-    cj = cand[:, None, :]                                   # value of j
-    ii = jax.lax.broadcasted_iota(jnp.int32, (1, n, n), 1)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (1, n, n), 2)
-    rank = jnp.sum(((cj > ci) | ((cj == ci) & (jj < ii))).astype(jnp.int32),
-                   axis=2)                                  # [BQ, n]
-    tgt = jax.lax.broadcasted_iota(jnp.int32, (1, k, n), 1)
-    sel = rank[:, None, :] == tgt                           # [BQ, k, n]
-    # where, not sel * cand: candidates are -inf-padded and 0 * -inf = NaN
-    # in eager IEEE semantics (jit happens to fold the one-hot away).
-    picked = jnp.where(sel, cand[:, None, :], jnp.zeros_like(cand)[:, None, :])
-    return jnp.sum(picked, axis=2)                          # [BQ, k]
+    Both inputs are sorted, so a stable merge's ranks come from one
+    [BQ, k, m] comparison: heap[i] lands at i + #(rows beating it), and
+    row[j] at j + #(heap entries at least as large) — ties keep the heap
+    entry first, so the ranks are a permutation of 0..k+m-1.  Output slot
+    r then selects the one candidate of rank r.  Branch-free VPU work,
+    with O(k * (k + m)) intermediates instead of an all-pairs rank over
+    the k + m candidates."""
+    m = rows.shape[1]
+    beats = rows[:, None, :] > heap[:, :, None]              # [BQ, k, m]
+    rank_h = (jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+              + jnp.sum(beats.astype(jnp.int32), axis=2))    # [BQ, k]
+    rank_r = (jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+              + jnp.sum((~beats).astype(jnp.int32), axis=1))  # [BQ, m]
+    tgt = jax.lax.broadcasted_iota(jnp.int32, (1, k, 1), 1)
+    # where, not onehot * value: candidates are -inf-padded and
+    # 0 * -inf = NaN.  Slot r < k has exactly one owner across the two
+    # selections, so the other contributes an exact 0.
+    from_h = jnp.sum(jnp.where(rank_h[:, None, :] == tgt,
+                               heap[:, None, :], 0.0), axis=2)
+    from_r = jnp.sum(jnp.where(rank_r[:, None, :] == tgt,
+                               rows[:, None, :], 0.0), axis=2)
+    return from_h + from_r                                   # [BQ, k]
 
 
 def _topk_init_kernel(plane_ref, mask_ref, heap_ref, scratch, *, k):
     BP, K = plane_ref.shape
-    BQ = mask_ref.shape[1]
+    kk = min(K, k)              # rows are sorted: only their top k can land
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, BP), 1)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         scratch[...] = jnp.full_like(scratch, -jnp.inf)
 
-    def body(j, heap):
-        prow = plane_ref[j, :]                              # [K]
-        m = mask_ref[j, :]                                  # [BQ]
-        rows = jnp.where(m[:, None] > 0, prow[None, :], -jnp.inf)
-        return _merge_topk_rows(heap, rows, k)
+    mask = mask_ref[...]                                     # [BQ, BP]
 
-    heap = jax.lax.fori_loop(0, BP, body, scratch[...])
-    scratch[...] = heap
-    heap_ref[...] = heap
+    # Most tiles hold no candidate of any of the tile's queries (the
+    # masks are selective queries' FULL partitions): skip them whole.
+    @pl.when(jnp.any(mask > 0))
+    def _fold():
+        def body(j, heap):
+            # column j of the mask as [BQ, 1]: a masked lane reduction,
+            # since Mosaic has no dynamic lane-dim indexing
+            m = jnp.max(jnp.where(lane == j, mask, 0.0), axis=1,
+                        keepdims=True)
+
+            def merge(h):
+                prow = plane_ref[pl.ds(j, 1), :][:, :kk]     # [1, kk]
+                rows = jnp.where(m > 0, prow, -jnp.inf)      # [BQ, kk]
+                return _merge_sorted_rows(h, rows, k)
+
+            return jax.lax.cond(jnp.any(m > 0), merge, lambda h: h, heap)
+
+        scratch[...] = jax.lax.fori_loop(0, BP, body, scratch[...])
+
+    heap_ref[...] = scratch[...]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def topk_init_batched(
-    plane: jax.Array,     # [P, K] f32 resident block-top-k rows, -inf padded
-    mask: jax.Array,      # [P, Q] f32, 1.0 = candidate partition for query q
+    plane: jax.Array,     # [P, K] f32 resident block-top-k rows, each row
+                          #        sorted descending, -inf padded
+    mask: jax.Array,      # [Q, P] f32, 1.0 = candidate partition for query q
     k: int,
     interpret: bool = False,
 ) -> jax.Array:
     """Per-query top-k over masked unions of resident block-top-k rows.
 
     Returns heap [Q, k] f32 descending (-inf padded): row q holds the k
-    largest plane values among partitions with ``mask[p, q] == 1`` — the
+    largest plane values among partitions with ``mask[q, p] == 1`` — the
     Sec. 5.4 upfront boundary for query q is ``heap[q, kq - 1]`` for any
     kq <= k (a prefix of a larger heap is the exact smaller-k answer, so
     one launch serves a whole group of queries with mixed k).
 
     The partition dimension is blocked with the heaps carried across grid
     steps in VMEM scratch, like ``topk_boundary``; queries ride the
-    sublane dim like ``minmax_prune_batched``.
+    sublane dim and partitions the lanes, like ``minmax_prune_batched``.
     """
     P, K = plane.shape
-    Q = mask.shape[1]
+    Q = mask.shape[0]
     pad_q = (-Q) % BLOCK_QI
     if pad_q:
-        mask = jnp.pad(mask, ((0, 0), (0, pad_q)))
+        mask = jnp.pad(mask, ((0, pad_q), (0, 0)))
     pad_p = (-P) % BLOCK_PI
     if pad_p:
         plane = jnp.pad(plane, ((0, pad_p), (0, 0)), constant_values=-jnp.inf)
-        mask = jnp.pad(mask, ((0, pad_p), (0, 0)))
+        mask = jnp.pad(mask, ((0, 0), (0, pad_p)))
     Qp, Pp = Q + pad_q, P + pad_p
     grid = (Qp // BLOCK_QI, Pp // BLOCK_PI)
     heap = pl.pallas_call(
@@ -171,7 +192,7 @@ def topk_init_batched(
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_PI, K), lambda i, j: (j, 0)),
-            pl.BlockSpec((BLOCK_PI, BLOCK_QI), lambda i, j: (j, i)),
+            pl.BlockSpec((BLOCK_QI, BLOCK_PI), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((BLOCK_QI, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Qp, k), plane.dtype),

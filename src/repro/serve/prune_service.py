@@ -190,6 +190,8 @@ class PruningService:
                                        # batch and serve repeats without
                                        # a launch (False: PR 8 behavior)
     ):
+        if mode == "pallas":
+            kops.kernel_interpret(mode)   # raises without a TPU
         self.mode = mode
         if cache is None:
             cache = DeviceStatsCache(
@@ -265,6 +267,16 @@ class PruningService:
         unsharded when the jnp-oracle footprint exceeds the slab
         bound — the counter reports what ran, not eligibility)."""
         return 1 if kops.last_launch_shards() > 1 else 0
+
+    @staticmethod
+    def _tree_ran(tree: bool) -> int:
+        """1 when the launch that just returned ran the hierarchical
+        path: a tree rung falls back to the flat launch inside the
+        wrapper when the table is too small for the tree geometry or the
+        coarse survivors are dense, and ``tree_launches`` counts what
+        ran, like ``_sharded``."""
+        return 1 if tree and kops.last_tree_stats().get("path") == "tree" \
+            else 0
 
     # -- DML bookkeeping ----------------------------------------------------
 
@@ -429,7 +441,7 @@ class PruningService:
                             range_lists, dstats, self.mode, mesh=mesh)
                     self.counters.bump("filter", launches=1,
                                        sharded=self._sharded(),
-                                       tree=1 if tree else 0)
+                                       tree=self._tree_ran(tree))
                 return tv
             return thunk
 
@@ -682,7 +694,7 @@ class PruningService:
                             part_ids_lists=part_ids, mesh=mesh)
                     self.counters.bump("join", launches=1,
                                        sharded=self._sharded(),
-                                       tree=1 if tree else 0)
+                                       tree=self._tree_ran(tree))
                 return hit
             return thunk
 
@@ -725,7 +737,7 @@ class PruningService:
                             self.mode, part_ids_lists=part_ids, mesh=mesh)
                     self.counters.bump("join_bloom", launches=1,
                                        sharded=self._sharded(),
-                                       tree=1 if tree else 0)
+                                       tree=self._tree_ran(tree))
                 return hit
             return thunk
 
@@ -814,7 +826,7 @@ class PruningService:
                             plane, masks, kb, self.mode, mesh=mesh)
                     self.counters.bump("topk", launches=1,
                                        sharded=self._sharded(),
-                                       tree=1 if tree else 0)
+                                       tree=self._tree_ran(tree))
                 return heap
             return thunk
 
@@ -941,12 +953,13 @@ class PruningService:
                 tech.run_batch(pipeline, states,
                                service=self if device else None)
             good = [pipeline.finish(s) for s in states]
-        except Exception:
+        except Exception as exc:
             # Last-resort guard: something outside the ladder's reach
             # broke the batched drive (a host-stage bug, a summary raise).
             # Salvage per query; a query that still fails degrades to a
             # passthrough report instead of taking the batch down.
             self.resilience["salvaged_batches"] += 1
+            self.ladder.last_errors["run_batch"] = exc
             good = []
             for _i, q in valid:
                 try:

@@ -41,7 +41,7 @@ from __future__ import annotations
 import dataclasses
 import random
 import time
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -275,6 +275,12 @@ class DegradationLadder:
     makes the final rung infallible (host passthrough); if every rung
     raises anyway the last exception propagates — that is a bug in the
     rung list, not a degradation.
+
+    ``last_errors`` maps each rung the ladder demoted *from* to the last
+    exception that made it give up — the demotion counters say how
+    often, this says why (a kernel the chip's compiler refused, a torn
+    plane, an injected fault).  ``PruningService.run_batch`` files its
+    batch-level salvage there too, under ``"run_batch"``.
     """
 
     def __init__(self, policy: Optional[BackoffPolicy] = None,
@@ -289,6 +295,7 @@ class DegradationLadder:
         self._rng = random.Random(seed)
         self.counters = (counters if counters is not None
                          else new_resilience_counters())
+        self.last_errors: Dict[str, BaseException] = {}
 
     def _expired(self, start: float) -> bool:
         return (self.deadline_s is not None
@@ -323,6 +330,7 @@ class DegradationLadder:
                     if name == "passthrough":
                         c["passthroughs"] += 1
                     return result, name
+            self.last_errors[name] = last_exc
             if ri + 1 < len(rungs):
                 c["demotions"][rungs[ri + 1][0]] = \
                     c["demotions"].get(rungs[ri + 1][0], 0) + 1

@@ -1,10 +1,12 @@
 """Filter pruning (paper Sec. 3): soundness, paper examples, fast path."""
 
 import numpy as np
+import pytest
 from hypothesis import given, settings
 
 from repro.core import expr as E
-from repro.core.metadata import FULL_MATCH, NO_MATCH, PARTIAL_MATCH
+from repro.core.metadata import (FULL_MATCH, NO_MATCH, PARTIAL_MATCH,
+                                 ColumnMeta, PartitionStats)
 from repro.core.prune_filter import (eval_ranges_tv, eval_tv, extract_ranges,
                                      fully_matching_two_pass)
 from repro.core.rowval import matches
@@ -220,3 +222,45 @@ class TestNullSemantics:
         np.testing.assert_array_equal(tv, [FULL_MATCH, NO_MATCH, PARTIAL_MATCH])
         tv = eval_tv(E.is_not_null(E.col("x")), tbl.stats)
         np.testing.assert_array_equal(tv, [NO_MATCH, FULL_MATCH, PARTIAL_MATCH])
+
+
+def _loop_stats(columns, encoded, null_masks, part_bounds):
+    """Partition-at-a-time reference for PartitionStats.from_columns."""
+    P, C = len(part_bounds) - 1, len(columns)
+    mins = np.full((P, C), np.inf)
+    maxs = np.full((P, C), -np.inf)
+    nulls = np.zeros((P, C), dtype=np.int64)
+    for ci, col in enumerate(columns):
+        for p in range(P):
+            s, e = part_bounds[p], part_bounds[p + 1]
+            v = encoded[col.name][s:e]
+            m = null_masks.get(col.name)
+            if m is not None:
+                nulls[p, ci] = int(m[s:e].sum())
+                v = v[~m[s:e]]
+            if v.size:
+                mins[p, ci], maxs[p, ci] = v.min(), v.max()
+    return mins, maxs, nulls
+
+
+class TestPartitionStatsBuild:
+    @pytest.mark.parametrize("bounds,null_frac", [
+        ([0, 8, 16, 24], 0.0),
+        ([0, 3, 3, 10, 10, 10, 24], 0.3),      # empty partitions
+        ([0, 1, 2, 24], 0.5),
+        ([0, 24], 1.0),                        # an all-null partition
+    ])
+    def test_segmented_reduction_matches_partition_loop(self, bounds,
+                                                        null_frac):
+        rng = np.random.default_rng(len(bounds))
+        cols = [ColumnMeta("a", "int"), ColumnMeta("b", "float")]
+        enc = {"a": rng.integers(-50, 50, 24).astype(np.float64),
+               "b": rng.uniform(-1, 1, 24)}
+        masks = {"b": rng.random(24) < null_frac} if null_frac else {}
+        bounds = np.asarray(bounds, dtype=np.int64)
+        st = PartitionStats.from_columns(cols, enc, masks, bounds)
+        mins, maxs, nulls = _loop_stats(cols, enc, masks, bounds)
+        np.testing.assert_array_equal(st.mins, mins)
+        np.testing.assert_array_equal(st.maxs, maxs)
+        np.testing.assert_array_equal(st.null_counts, nulls)
+        np.testing.assert_array_equal(st.row_counts, np.diff(bounds))

@@ -230,6 +230,23 @@ class TestDegradationLadder:
         with pytest.raises(KeyError):
             lad.execute([("device", bad), ("host_kernel", bad)])
 
+    def test_last_errors_name_each_demoted_rung(self):
+        lad, _clock, _c = self._ladder(policy=BackoffPolicy(retries=0))
+        first, second = RuntimeError("compile refused"), KeyError("torn")
+
+        def raise_(exc):
+            def thunk():
+                raise exc
+            return thunk
+
+        _, rung = lad.execute([("tree", raise_(first)),
+                               ("device", raise_(second)),
+                               ("host_kernel", lambda: 1)])
+        assert rung == "host_kernel"
+        assert lad.last_errors == {"tree": first, "device": second}
+        lad.execute([("device", lambda: 2)])     # success keeps the record
+        assert lad.last_errors["device"] is second
+
     def test_rung_order_matches_contract(self):
         assert RUNGS == ("verdict", "sharded_tree", "tree", "sharded",
                          "device", "host_kernel", "host_oracle",

@@ -136,9 +136,9 @@ class TestTopKInitBatchedKernel:
         rng = np.random.default_rng(seed)
         plane, mask = _make_init_inputs(P, K, Q, rng)
         out_k = np.asarray(topk_init_batched(
-            jnp.asarray(plane), jnp.asarray(mask.T), k, interpret=True))
+            jnp.asarray(plane), jnp.asarray(mask), k, interpret=True))
         out_r = np.asarray(ref.topk_init_batched_ref(
-            jnp.asarray(plane), jnp.asarray(mask.T), k))
+            jnp.asarray(plane), jnp.asarray(mask), k))
         oracle = _init_oracle(plane, mask, k)
         np.testing.assert_array_equal(out_k, oracle)
         np.testing.assert_array_equal(out_r, oracle)
