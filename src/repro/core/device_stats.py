@@ -52,6 +52,7 @@ from typing import Callable, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..spans import span
 from .metadata import NO_MATCH, PartitionStats
 from .predicate_cache import TableVersion
 
@@ -78,8 +79,10 @@ def plane_checksum(arrays) -> int:
     always verifies.
     """
     c = 0
-    for a in arrays:
-        c = zlib.crc32(np.ascontiguousarray(np.asarray(a)).tobytes(), c)
+    with span("planes.verify",
+              bytes=sum(int(getattr(a, "nbytes", 0)) for a in arrays)):
+        for a in arrays:
+            c = zlib.crc32(np.ascontiguousarray(np.asarray(a)).tobytes(), c)
     return c
 
 _F32_NEG = np.float32(-np.inf)
@@ -987,54 +990,55 @@ class DeviceStatsCache:
         capacity overflow, unknown kind); on success only the changed
         partition columns were staged.
         """
-        stats = table.stats
-        if stats.num_partitions > e.capacity:
-            return False
-        mins, maxs, dem = e.planes
-        nbytes = 0
-        for d in deltas:
-            if d.kind == "append":
-                m32, x32, dm = self._stat_cols(stats, d.part_lo, d.part_hi)
-                sl = slice(d.part_lo, d.part_hi)
-                mins = mins.at[:, sl].set(jnp.asarray(m32))
-                maxs = maxs.at[:, sl].set(jnp.asarray(x32))
-                dem = dem.at[:, sl].set(jnp.asarray(dm))
-                nbytes += int(m32.nbytes + x32.nbytes + dm.nbytes)
-            elif d.kind == "drop":
-                ids = jnp.asarray(np.asarray(d.part_ids, dtype=np.int32))
-                mins = mins.at[:, ids].set(_F32_MAX)
-                maxs = maxs.at[:, ids].set(-_F32_MAX)
-                dem = dem.at[:, ids].set(np.float32(1.0))
-                nbytes += 3 * e.num_columns * len(d.part_ids) * 4
-            elif d.kind == "update":
-                try:
-                    ci = stats.col_id(d.column)
-                except KeyError:
-                    return False
-                P = stats.num_partitions
-                m32, x32, inexact = cast_stats_f32(
-                    stats.mins[:, ci][None, :], stats.maxs[:, ci][None, :])
-                dm = ((stats.null_counts[:, ci][None, :] > 0)
-                      | inexact).astype(np.float32)
-                mins = mins.at[ci, :P].set(jnp.asarray(m32[0]))
-                maxs = maxs.at[ci, :P].set(jnp.asarray(x32[0]))
-                dem = dem.at[ci, :P].set(jnp.asarray(dm[0]))
-                nbytes += 3 * P * 4
-            else:                      # rewrite (or unknown): full restage
+        with span("planes.stage", P=table.stats.num_partitions):
+            stats = table.stats
+            if stats.num_partitions > e.capacity:
                 return False
-        # re-stamp from the clean replayed arrays, then let the chaos
-        # seam tear bytes *after* the stamp (exactly the corruption the
-        # verifier must catch); one atomic tuple store: an in-flight
-        # launch that already read e.planes_state keeps a consistent
-        # pre-replay (planes, P) pair, and a later read sees the full
-        # post-replay pair — never a mix
-        e.checksum = plane_checksum((mins, maxs, dem))
-        e.planes_state = (self._corrupt("stage.stat", (mins, maxs, dem)),
-                          stats.num_partitions)
-        e.live_count = self._live_count(table)
-        self.staged_bytes += nbytes
-        self.delta_stages += 1
-        return True
+            mins, maxs, dem = e.planes
+            nbytes = 0
+            for d in deltas:
+                if d.kind == "append":
+                    m32, x32, dm = self._stat_cols(stats, d.part_lo, d.part_hi)
+                    sl = slice(d.part_lo, d.part_hi)
+                    mins = mins.at[:, sl].set(jnp.asarray(m32))
+                    maxs = maxs.at[:, sl].set(jnp.asarray(x32))
+                    dem = dem.at[:, sl].set(jnp.asarray(dm))
+                    nbytes += int(m32.nbytes + x32.nbytes + dm.nbytes)
+                elif d.kind == "drop":
+                    ids = jnp.asarray(np.asarray(d.part_ids, dtype=np.int32))
+                    mins = mins.at[:, ids].set(_F32_MAX)
+                    maxs = maxs.at[:, ids].set(-_F32_MAX)
+                    dem = dem.at[:, ids].set(np.float32(1.0))
+                    nbytes += 3 * e.num_columns * len(d.part_ids) * 4
+                elif d.kind == "update":
+                    try:
+                        ci = stats.col_id(d.column)
+                    except KeyError:
+                        return False
+                    P = stats.num_partitions
+                    m32, x32, inexact = cast_stats_f32(
+                        stats.mins[:, ci][None, :], stats.maxs[:, ci][None, :])
+                    dm = ((stats.null_counts[:, ci][None, :] > 0)
+                          | inexact).astype(np.float32)
+                    mins = mins.at[ci, :P].set(jnp.asarray(m32[0]))
+                    maxs = maxs.at[ci, :P].set(jnp.asarray(x32[0]))
+                    dem = dem.at[ci, :P].set(jnp.asarray(dm[0]))
+                    nbytes += 3 * P * 4
+                else:                      # rewrite (or unknown): full restage
+                    return False
+            # re-stamp from the clean replayed arrays, then let the chaos
+            # seam tear bytes *after* the stamp (exactly the corruption the
+            # verifier must catch); one atomic tuple store: an in-flight
+            # launch that already read e.planes_state keeps a consistent
+            # pre-replay (planes, P) pair, and a later read sees the full
+            # post-replay pair — never a mix
+            e.checksum = plane_checksum((mins, maxs, dem))
+            e.planes_state = (self._corrupt("stage.stat", (mins, maxs, dem)),
+                              stats.num_partitions)
+            e.live_count = self._live_count(table)
+            self.staged_bytes += nbytes
+            self.delta_stages += 1
+            return True
 
     def get(self, table, tv: Optional[TableVersion] = None) -> DeviceStats:
         """The table's resident DeviceStats: staged on first touch,
@@ -1085,10 +1089,11 @@ class DeviceStatsCache:
             retried = False
             while True:
                 self._fire("stage.stat")
-                e = DeviceStats.stage(
-                    table.stats, table.name, tver,
-                    capacity=plane_capacity(table.stats.num_partitions),
-                    live=getattr(table, "live", None))
+                with span("planes.stage", P=table.stats.num_partitions):
+                    e = DeviceStats.stage(
+                        table.stats, table.name, tver,
+                        capacity=plane_capacity(table.stats.num_partitions),
+                        live=getattr(table, "live", None))
                 e.tv_version = tvv
                 planes, logical_p = e.planes_state
                 e.planes_state = (self._corrupt("stage.stat", planes),
@@ -1148,18 +1153,20 @@ class DeviceStatsCache:
                 ok = True
                 staged = False
                 nbytes = 0
-                for d in deltas:
-                    if d.kind == "append":
-                        nbytes += append_fn(e, table, d.part_lo, d.part_hi)
-                        staged = True
-                    elif d.kind == "drop":
-                        nbytes += drop_fn(e, table, d.part_ids)
-                        staged = True
-                    elif d.kind == "update" and d.column != column:
-                        continue
-                    else:
-                        ok = False
-                        break
+                with span("planes.stage", P=table.stats.num_partitions):
+                    for d in deltas:
+                        if d.kind == "append":
+                            nbytes += append_fn(e, table, d.part_lo,
+                                                d.part_hi)
+                            staged = True
+                        elif d.kind == "drop":
+                            nbytes += drop_fn(e, table, d.part_ids)
+                            staged = True
+                        elif d.kind == "update" and d.column != column:
+                            continue
+                        else:
+                            ok = False
+                            break
                 if ok:
                     e.version = tver
                     e.logical_p = table.stats.num_partitions
@@ -1198,7 +1205,8 @@ class DeviceStatsCache:
         retried = False
         while True:
             self._fire(f"stage.{family}")
-            e = build_fn()
+            with span("planes.stage"):
+                e = build_fn()
             e.meta["checksum"] = plane_checksum(e.arrays)
             e.arrays = self._corrupt(f"stage.{family}", e.arrays)
             e = self._plane_put(family, store, key, e)
@@ -1537,7 +1545,10 @@ class DeviceStatsCache:
                 elif geometry_ok and e.version < tver:
                     deltas = self._deltas_since(table, e.version)
                     if deltas is not None:
-                        nbytes = self._tree_replay(e, table, dstats, deltas)
+                        with span("planes.stage",
+                                  P=table.stats.num_partitions):
+                            nbytes = self._tree_replay(e, table, dstats,
+                                                       deltas)
                         if nbytes is not None:
                             e.version = tver
                             e.logical_p = table.stats.num_partitions
@@ -1612,28 +1623,29 @@ class DeviceStatsCache:
                     ok = True
                     staged = False
                     nbytes = 0
-                    for d in deltas:
-                        if d.kind == "append":
-                            sub = table.stats.select(
-                                np.arange(d.part_lo, d.part_hi))
-                            patch = eval_tv(pred, sub).astype(np.int8)
-                            row = row.at[d.part_lo:d.part_hi].set(
-                                jnp.asarray(patch))
-                            self.integrity["verdict_repairs"] += 1
-                            nbytes += d.part_hi - d.part_lo
-                            staged = True
-                        elif d.kind == "drop":
-                            ids = jnp.asarray(
-                                np.asarray(d.part_ids, dtype=np.int32))
-                            row = row.at[ids].set(np.int8(NO_MATCH))
-                            nbytes += len(d.part_ids)
-                            staged = True
-                        elif d.kind == "update" and \
-                                d.column not in e.meta["cols"]:
-                            continue
-                        else:       # rewrite / predicate-column update
-                            ok = False
-                            break
+                    with span("planes.stage", P=P):
+                        for d in deltas:
+                            if d.kind == "append":
+                                sub = table.stats.select(
+                                    np.arange(d.part_lo, d.part_hi))
+                                patch = eval_tv(pred, sub).astype(np.int8)
+                                row = row.at[d.part_lo:d.part_hi].set(
+                                    jnp.asarray(patch))
+                                self.integrity["verdict_repairs"] += 1
+                                nbytes += d.part_hi - d.part_lo
+                                staged = True
+                            elif d.kind == "drop":
+                                ids = jnp.asarray(
+                                    np.asarray(d.part_ids, dtype=np.int32))
+                                row = row.at[ids].set(np.int8(NO_MATCH))
+                                nbytes += len(d.part_ids)
+                                staged = True
+                            elif d.kind == "update" and \
+                                    d.column not in e.meta["cols"]:
+                                continue
+                            else:   # rewrite / predicate-column update
+                                ok = False
+                                break
                     if ok:
                         e.arrays = (row,)
                         e.version = tver

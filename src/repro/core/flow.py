@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..spans import span
 from . import expr as E
 from .metadata import (NO_MATCH, PARTIAL_MATCH, ScanSet, live_full_scan,
                        mask_dead_partitions, pruning_ratio)
@@ -294,10 +295,12 @@ class JoinTechnique(Technique):
     def _build_keys(self, state: PruneState) -> np.ndarray:
         q = state.query
         bspec = q.scans[q.join.build]
-        bctx = bspec.table.ctx_for(state.scan_sets[q.join.build].part_ids)
-        bmask = matches(bspec.pred, bctx)
-        keys, knulls = bctx.col(q.join.build_key)
-        return keys[bmask & ~knulls]
+        part_ids = state.scan_sets[q.join.build].part_ids
+        with span("join.build_keys", P=len(part_ids)):
+            bctx = bspec.table.ctx_for(part_ids)
+            bmask = matches(bspec.pred, bctx)
+            keys, knulls = bctx.col(q.join.build_key)
+            return keys[bmask & ~knulls]
 
     def _summarize(self, pipe, state) -> Optional[BuildSummary]:
         """Host part of the stage: build keys + summary (also feeds the
@@ -307,8 +310,9 @@ class JoinTechnique(Technique):
         state.build_keys = self._build_keys(state)
         if not pipe.enable_join:
             return None
-        return summarize_build(state.build_keys,
-                               ndv_limit=pipe.join_ndv_limit)
+        with span("join.summarize", rows=len(state.build_keys)):
+            return summarize_build(state.build_keys,
+                                   ndv_limit=pipe.join_ndv_limit)
 
     def _apply(self, pipe, state, summary: BuildSummary,
                hit: Optional[np.ndarray]) -> None:
@@ -317,13 +321,15 @@ class JoinTechnique(Technique):
         kind (None -> host matcher)."""
         q = state.query
         scan = state.scan_sets[q.join.probe]
-        over = None if hit is None else np.asarray(hit)[scan.part_ids] > 0
-        res = prune_probe(
-            scan, q.scans[q.join.probe].table.stats,
-            q.join.probe_key, summary,
-            distinct_hit=over if summary.distinct is not None else None,
-            bloom_hit=over if summary.bloom is not None else None,
-        )
+        with span("join.apply", P=len(scan)):
+            over = (None if hit is None
+                    else np.asarray(hit)[scan.part_ids] > 0)
+            res = prune_probe(
+                scan, q.scans[q.join.probe].table.stats,
+                q.join.probe_key, summary,
+                distinct_hit=over if summary.distinct is not None else None,
+                bloom_hit=over if summary.bloom is not None else None,
+            )
         state.scan_sets[q.join.probe] = res.scan
         state.per_scan[q.join.probe]["join"] = TechniqueReport(
             res.partitions_before, res.partitions_after,
@@ -501,10 +507,11 @@ class TopKTechnique(Technique):
                 (table, order_col, bool(desc), []))[3].append(
                     (st, extra, q.effective_k))
         for table, col, desc, members in groups.values():
-            floors = service.topk_init_batch(
-                table, col, desc,
-                [(st.scan_sets[st.query.order_by[0]], k)
-                 for st, _, k in members])
+            with span("topk.init", q=len(members)):
+                floors = service.topk_init_batch(
+                    table, col, desc,
+                    [(st.scan_sets[st.query.order_by[0]], k)
+                     for st, _, k in members])
             for (st, extra, _k), floor in zip(members, floors):
                 self._apply(pipe, st, extra, floor, "device")
         for st, extra in host_jobs:

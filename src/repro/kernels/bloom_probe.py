@@ -164,6 +164,7 @@ def bloom_probe_batched(
         out_shape=jax.ShapeDtypeStruct((Qp, Pp), jnp.int32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="bloom_probe_batched",
     )(pmin.reshape(-1, 1, BLOCK_PB), width.reshape(-1, 1, BLOCK_PB),
       lo_t.reshape(Qp * BLOCK_WORDS, Bb),
       hi_t.reshape(Qp * BLOCK_WORDS, Bb))

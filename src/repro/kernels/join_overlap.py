@@ -127,6 +127,7 @@ def join_overlap_batched(
         out_specs=pl.BlockSpec((BLOCK_QB, BLOCK_P), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Qp, Pp), jnp.int32),
         interpret=interpret,
+        name="join_overlap_batched",
     )(dist, pmin[None, :], pmax[None, :])
     return hit[:Q, :P]
 

@@ -135,5 +135,6 @@ def minmax_prune_batched(
         out_specs=pl.BlockSpec((BLOCK_Q, BLOCK_P), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Qp, Pp), jnp.int32),
         interpret=interpret,
+        name="minmax_prune_batched",
     )(cids, lo, hi, mins, maxs, demote)
     return tv[:Q, :P]

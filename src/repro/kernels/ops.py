@@ -45,6 +45,7 @@ from ..core.device_stats import (TREE_MIN_GROUPS, DeviceStats,
                                  snap_bounds_integral)
 from ..core.metadata import PartitionStats
 from ..core.prune_join import BLOCK_WORDS
+from ..spans import span
 from . import ref
 from .bloom_probe import bloom_probe_batched
 from .join_overlap import join_overlap, join_overlap_batched
@@ -360,6 +361,20 @@ def pack_ranges(
 _batched_ref_jit = jax.jit(ref.minmax_prune_batched_ref)
 
 
+def _fetch(out) -> np.ndarray:
+    """A launch's result on the host, in two spans: ``device_wait`` holds
+    the device's compute, ``d2h`` only the transfer (and any relayout) of
+    the ready result.  ``out`` may be a list of slabs along the last
+    axis, which arrive as one array."""
+    with span("device_wait"):
+        jax.block_until_ready(out)
+    if isinstance(out, list):
+        with span("d2h", bytes=sum(int(o.nbytes) for o in out)):
+            return np.concatenate([np.asarray(o) for o in out], axis=-1)
+    with span("d2h", bytes=int(out.nbytes)):
+        return np.asarray(out)
+
+
 def prune_ranges_batched_device(
     range_lists: Sequence[List[Tuple[int, float, float]]],
     dstats: DeviceStats,
@@ -380,48 +395,48 @@ def prune_ranges_batched_device(
     launch (partitions are independent).
     """
     Q = len(range_lists)
-    # one consistent snapshot: a concurrent delta replay swaps the whole
-    # (planes, logical P) pair atomically, so a single read here can
-    # never mix post-DML planes with a pre-DML partition count (or
-    # vice versa)
-    planes, P = dstats.planes_state
-    mins, maxs, demote = planes
-    Pc = int(mins.shape[1])            # staged capacity (>= P; sentinel tail)
-    cids, lo, hi, full_safe = pack_ranges(range_lists, dstats)
-    Qb = cids.shape[0]
-    cids_d = jnp.asarray(cids)
-    lo_d = jnp.asarray(lo)
-    hi_d = jnp.asarray(hi)
+    with span("pack", q=Q):
+        # one consistent snapshot: a concurrent delta replay swaps the
+        # whole (planes, logical P) pair atomically, so a single read here
+        # can never mix post-DML planes with a pre-DML partition count (or
+        # vice versa)
+        planes, P = dstats.planes_state
+        mins, maxs, demote = planes
+        Pc = int(mins.shape[1])        # staged capacity (>= P; sentinel tail)
+        cids, lo, hi, full_safe = pack_ranges(range_lists, dstats)
+        Qb = cids.shape[0]
+        cids_d = jnp.asarray(cids)
+        lo_d = jnp.asarray(lo)
+        hi_d = jnp.asarray(hi)
     shards = mesh_shards(mesh, Pc)
     if (shards > 1 and not _use_kernel(mode)
             and Qb * Pc // shards > _REF_SLAB_ELEMS):
         shards = 1     # per-shard jnp body would exceed the slab bound;
                        # the unsharded path below slabs instead
     _note_shards(shards)
-    if shards > 1:
-        fn = _sharded_minmax(mesh, *_body_flags(mode))
-        tv = np.asarray(fn(cids_d, lo_d, hi_d, mins, maxs, demote))
-    elif mode == "ref" or (mode == "auto" and not _on_tpu()):
-        slab = max(1024, _REF_SLAB_ELEMS // Qb)
-        if slab >= Pc:
-            tv = np.asarray(_batched_ref_jit(
-                cids_d, lo_d, hi_d, mins, maxs, demote))
-        else:
-            tv = np.empty((Qb, Pc), dtype=np.int32)
-            for s in range(0, Pc, slab):
-                e = min(s + slab, Pc)
-                tv[:, s:e] = np.asarray(_batched_ref_jit(
+    with span("dispatch", q=Q, P=P):
+        if shards > 1:
+            fn = _sharded_minmax(mesh, *_body_flags(mode))
+            out = fn(cids_d, lo_d, hi_d, mins, maxs, demote)
+        elif mode == "ref" or (mode == "auto" and not _on_tpu()):
+            slab = max(1024, _REF_SLAB_ELEMS // Qb)
+            if slab >= Pc:
+                out = _batched_ref_jit(cids_d, lo_d, hi_d, mins, maxs, demote)
+            else:
+                out = [_batched_ref_jit(
                     cids_d, lo_d, hi_d,
-                    jax.lax.slice_in_dim(mins, s, e, axis=1),
-                    jax.lax.slice_in_dim(maxs, s, e, axis=1),
-                    jax.lax.slice_in_dim(demote, s, e, axis=1)))
-    else:
-        tv = np.asarray(minmax_prune_batched(
-            cids_d, lo_d, hi_d, mins, maxs, demote,
-            interpret=kernel_interpret(mode)))
-    tv = tv[:Q, :P].astype(np.int8)
-    if not full_safe.all():
-        tv[~full_safe] = np.minimum(tv[~full_safe], 1)
+                    jax.lax.slice_in_dim(mins, s, min(s + slab, Pc), axis=1),
+                    jax.lax.slice_in_dim(maxs, s, min(s + slab, Pc), axis=1),
+                    jax.lax.slice_in_dim(demote, s, min(s + slab, Pc), axis=1))
+                    for s in range(0, Pc, slab)]
+        else:
+            out = minmax_prune_batched(cids_d, lo_d, hi_d, mins, maxs, demote,
+                                       interpret=kernel_interpret(mode))
+    tv = _fetch(out)
+    with span("unpack", q=Q, P=P):
+        tv = tv[:Q, :P].astype(np.int8)
+        if not full_safe.all():
+            tv[~full_safe] = np.minimum(tv[~full_safe], 1)
     return tv
 
 
@@ -590,50 +605,57 @@ def prune_ranges_batched_tree(
         _note_tree(path="flat_dense", groups=G, coarse_density=float(cdens))
         return prune_ranges_batched_device(range_lists, dstats, mode,
                                            mesh=mesh)
-    cids_d = jnp.asarray(cids)
-    lo_d = jnp.asarray(lo)
-    hi_d = jnp.asarray(hi)
-
     def pad_rows(a):
         return np.concatenate(
             [a, np.zeros((Qb - Q, a.shape[1]), dtype=a.dtype)], axis=0)
 
     # Level 1 — fine group pre-pass over coarse-survivor children only.
-    gpos = _survivor_positions(csurv, G // G2)            # [Q, S2b * f2]
-    tvg = np.asarray(_gathered_ref_jit(
-        cids_d, lo_d, hi_d, gm, gx, gd, jnp.asarray(pad_rows(gpos))))[:Q]
-    gsurv = np.zeros((Q, G), dtype=bool)
-    qrow = np.repeat(np.arange(Q), gpos.shape[1])
-    gsurv[qrow, gpos.reshape(-1)] = (tvg > 0).reshape(-1)
-    fdens = gsurv.sum(axis=1).max() / G
+    with span("pack", q=Q):
+        cids_d = jnp.asarray(cids)
+        lo_d = jnp.asarray(lo)
+        hi_d = jnp.asarray(hi)
+        gpos = _survivor_positions(csurv, G // G2)        # [Q, S2b * f2]
+        gpos_d = jnp.asarray(pad_rows(gpos))
+    with span("dispatch", q=Q, P=G):
+        out = _gathered_ref_jit(cids_d, lo_d, hi_d, gm, gx, gd, gpos_d)
+    tvg = _fetch(out)
+    with span("unpack", q=Q, P=G):
+        tvg = tvg[:Q]
+        gsurv = np.zeros((Q, G), dtype=bool)
+        qrow = np.repeat(np.arange(Q), gpos.shape[1])
+        gsurv[qrow, gpos.reshape(-1)] = (tvg > 0).reshape(-1)
+        fdens = gsurv.sum(axis=1).max() / G
     # Level 2 — gathered leaf evaluation over surviving groups' members,
     # slabbed like the flat ref path (slab and W are both pow-2 multiples
     # of fanout, so chunk widths repeat and recompiles stay bounded).
-    pos = _survivor_positions(gsurv, fanout)              # [Q, Sb * fanout]
-    W = pos.shape[1]
-    groups_per_slab = max(1, (_REF_SLAB_ELEMS // max(Qb, 1)) // fanout)
-    slab = fanout * (1 << (groups_per_slab.bit_length() - 1))
-    pos_d = jnp.asarray(pad_rows(pos))
-    if W <= slab:
-        tvl = np.asarray(_gathered_ref_jit(
-            cids_d, lo_d, hi_d, mins, maxs, demote, pos_d))[:Q]
-    else:
-        tvl = np.empty((Q, W), dtype=np.int32)
-        for s in range(0, W, slab):
-            e = min(s + slab, W)
-            tvl[:, s:e] = np.asarray(_gathered_ref_jit(
+    with span("pack", q=Q):
+        pos = _survivor_positions(gsurv, fanout)          # [Q, Sb * fanout]
+        W = pos.shape[1]
+        groups_per_slab = max(1, (_REF_SLAB_ELEMS // max(Qb, 1)) // fanout)
+        slab = fanout * (1 << (groups_per_slab.bit_length() - 1))
+        pos_d = jnp.asarray(pad_rows(pos))
+    with span("dispatch", q=Q, P=P):
+        if W <= slab:
+            out = _gathered_ref_jit(cids_d, lo_d, hi_d, mins, maxs, demote,
+                                    pos_d)
+        else:
+            out = [_gathered_ref_jit(
                 cids_d, lo_d, hi_d, mins, maxs, demote,
-                jax.lax.slice_in_dim(pos_d, s, e, axis=1)))[:Q]
+                jax.lax.slice_in_dim(pos_d, s, min(s + slab, W), axis=1))
+                for s in range(0, W, slab)]
+    tvl = _fetch(out)
     _note_shards(1)
-    # Scatter — unlisted positions stay 0 (NO): every unlisted live
-    # partition sits in a pruned group, and group NO implies member NO.
-    tv = np.zeros((Q, P), dtype=np.int8)
-    ps = pos.reshape(-1)
-    live = ps < P                    # capacity-tail sentinel slots
-    qs = np.repeat(np.arange(Q), W)[live]
-    tv[qs, ps[live]] = tvl.reshape(-1)[live].astype(np.int8)
-    if not full_safe.all():
-        tv[~full_safe] = np.minimum(tv[~full_safe], 1)
+    with span("unpack", q=Q, P=P):
+        tvl = tvl[:Q]
+        # Scatter — unlisted positions stay 0 (NO): every unlisted live
+        # partition sits in a pruned group, and group NO implies member NO.
+        tv = np.zeros((Q, P), dtype=np.int8)
+        ps = pos.reshape(-1)
+        live = ps < P                    # capacity-tail sentinel slots
+        qs = np.repeat(np.arange(Q), W)[live]
+        tv[qs, ps[live]] = tvl.reshape(-1)[live].astype(np.int8)
+        if not full_safe.all():
+            tv[~full_safe] = np.minimum(tv[~full_safe], 1)
     _note_tree(path="tree", groups=G, coarse_density=float(cdens),
                fine_density=float(fdens), leaf_cols=int(W))
     return tv
@@ -923,12 +945,7 @@ def join_overlap_batched_device(
             and q_bucket(Q) * P // shards > _REF_SLAB_ELEMS):
         shards = 1     # keep the C-speed searchsorted fallback below
     _note_shards(shards)
-    if shards > 1:
-        fn = _sharded_join(mesh, *_body_flags(mode))
-        hit = np.asarray(fn(jnp.asarray(pack_distinct(distinct_lists)),
-                            pmin, pmax))
-        return hit[:Q]
-    if mode == "ref" or (mode == "auto" and not _on_tpu()):
+    if shards == 1 and (mode == "ref" or (mode == "auto" and not _on_tpu())):
         # np.asarray of a CPU-backed jax array is a view — the resident
         # plane is not copied.  A key k32 hits [pmin, pmax] iff
         # searchsorted brackets it: identical counts to the jnp oracle.
@@ -948,11 +965,17 @@ def join_overlap_batched_device(
             else:
                 hit[qi, ids] = row
         return hit
-    dist_d = jnp.asarray(pack_distinct(distinct_lists))
-    hit = np.asarray(join_overlap_batched(
-        dist_d, pmin, pmax,
-        interpret=kernel_interpret(mode)))
-    return hit[:Q]
+    with span("pack", q=Q):
+        dist_d = jnp.asarray(pack_distinct(distinct_lists))
+    with span("dispatch", q=Q, P=P):
+        if shards > 1:
+            out = _sharded_join(mesh, *_body_flags(mode))(dist_d, pmin, pmax)
+        else:
+            out = join_overlap_batched(dist_d, pmin, pmax,
+                                       interpret=kernel_interpret(mode))
+    hit = _fetch(out)
+    with span("unpack", q=Q, P=P):
+        return hit[:Q]
 
 
 def pack_blooms(blooms: Sequence) -> Tuple[np.ndarray, np.ndarray]:
@@ -1015,14 +1038,7 @@ def bloom_probe_batched_device(
         # unconditionally
         shards = 1
     _note_shards(shards)
-    if shards > 1:
-        lo, hi = pack_blooms(blooms)
-        width_eff = jnp.where(width <= enum_limit, width, 0).astype(jnp.int32)
-        fn = _sharded_bloom(mesh, *_body_flags(mode), eb)
-        hit = np.asarray(fn(jnp.asarray(lo), jnp.asarray(hi),
-                            pmin, width_eff))
-        return hit[:Q]
-    if mode == "ref" or (mode == "auto" and not _on_tpu()):
+    if shards == 1 and (mode == "ref" or (mode == "auto" and not _on_tpu())):
         # np.asarray of a CPU-backed jax array is a view — no copy.
         pmin_h = np.asarray(pmin)
         width_h = np.asarray(width)
@@ -1035,20 +1051,27 @@ def bloom_probe_batched_device(
             if not nids.size:
                 continue
             wq = width_h[nids]
-            span = int(wq.max())
+            span_w = int(wq.max())
             cand = (pmin_h[nids][:, None].astype(np.int64)
-                    + np.arange(span)[None, :])
-            valid = np.arange(span)[None, :] < wq[:, None]
+                    + np.arange(span_w)[None, :])
+            valid = np.arange(span_w)[None, :] < wq[:, None]
             hits = bloom.contains(cand.reshape(-1)).reshape(cand.shape)
             hit[qi, nids[~(hits & valid).any(axis=1)]] = 0
         return hit
-    lo, hi = pack_blooms(blooms)
-    width_eff = jnp.where(width <= enum_limit, width, 0).astype(jnp.int32)
-    eb = enum_bucket(max(1, min(int(wmax), int(enum_limit))))
-    hit = np.asarray(bloom_probe_batched(
-        jnp.asarray(lo), jnp.asarray(hi), pmin, width_eff, enum_pad=eb,
-        interpret=kernel_interpret(mode)))
-    return hit[:Q]
+    with span("pack", q=Q):
+        lo, hi = pack_blooms(blooms)
+        lo_d, hi_d = jnp.asarray(lo), jnp.asarray(hi)
+        width_eff = jnp.where(width <= enum_limit, width, 0).astype(jnp.int32)
+    with span("dispatch", q=Q, P=P):
+        if shards > 1:
+            fn = _sharded_bloom(mesh, *_body_flags(mode), eb)
+            out = fn(lo_d, hi_d, pmin, width_eff)
+        else:
+            out = bloom_probe_batched(lo_d, hi_d, pmin, width_eff, enum_pad=eb,
+                                      interpret=kernel_interpret(mode))
+    hit = _fetch(out)
+    with span("unpack", q=Q, P=P):
+        return hit[:Q]
 
 
 def topk_init_batched_device(
@@ -1084,16 +1107,7 @@ def topk_init_batched_device(
         shards = 1     # dense O(Q*P*K) oracle body: the sparse numpy
                        # gather below wins at fleet shapes
     _note_shards(shards)
-    if shards > 1:
-        mask_d = jnp.asarray(mask.astype(np.float32))      # [Q, Pp]
-        fn = _sharded_topk(mesh, *_body_flags(mode), k)
-        heaps = np.asarray(fn(plane, mask_d))             # [n, Q, k]
-        # Rank-selection merge of the per-shard heaps: top-k is a pure
-        # selection, so selecting k from the union of shard-local top-k
-        # heaps is exactly the global top-k (same value multiset).
-        allv = np.concatenate(list(heaps), axis=1)        # [Q, n*k]
-        return -np.sort(-allv, axis=1)[:, :k]
-    if mode == "ref" or (mode == "auto" and not _on_tpu()):
+    if shards == 1 and (mode == "ref" or (mode == "auto" and not _on_tpu())):
         plane_np = np.asarray(plane)
         heap = np.full((Q, k), -np.inf, dtype=np.float32)
         for qi in range(Q):
@@ -1109,7 +1123,21 @@ def topk_init_batched_device(
             top = np.sort(vals)[::-1]
             heap[qi, : top.size] = top
         return heap
-    heap = topk_init_batched(
-        plane, jnp.asarray(mask.astype(np.float32)), k,
-        interpret=kernel_interpret(mode))
-    return np.asarray(heap)
+    with span("pack", q=Q):
+        mask_d = jnp.asarray(mask.astype(np.float32))      # [Q, Pp]
+    with span("dispatch", q=Q, P=Pp):
+        if shards > 1:
+            out = _sharded_topk(mesh, *_body_flags(mode), k)(plane, mask_d)
+        else:
+            out = topk_init_batched(plane, mask_d, k,
+                                    interpret=kernel_interpret(mode))
+    heap = _fetch(out)
+    with span("unpack", q=Q, P=Pp):
+        if shards > 1:
+            # Rank-selection merge of the per-shard heaps [n, Q, k]: top-k
+            # is a pure selection, so selecting k from the union of
+            # shard-local top-k heaps is exactly the global top-k (same
+            # value multiset).
+            allv = np.concatenate(list(heap), axis=1)        # [Q, n*k]
+            heap = -np.sort(-allv, axis=1)[:, :k]
+    return heap

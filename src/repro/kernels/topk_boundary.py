@@ -198,6 +198,7 @@ def topk_init_batched(
         out_shape=jax.ShapeDtypeStruct((Qp, k), plane.dtype),
         scratch_shapes=[pltpu.VMEM((BLOCK_QI, k), plane.dtype)],
         interpret=interpret,
+        name="topk_init_batched",
     )(plane, mask)
     return heap[:Q]
 

@@ -22,9 +22,9 @@ shape of LLM serving systems applied to the pruning service:
     the prefetches so the ``PlaneMemoryManager`` can never evict a plane
     an in-flight launch is consuming (pins are global refcounts; the
     launch scope's own pins are taken on the worker thread).
-  * Every response carries queue/stage/launch timestamps, and a
-    ``counters["latency"]`` block (keys registered in
-    ``COUNTER_REGISTRY`` — CL006) accumulates per-batch p50/p99/max and
+  * Every response carries queue/stage/launch timestamps and its own
+    latency, and a ``counters["latency"]`` block (keys registered in
+    ``COUNTER_REGISTRY`` — CL006) counts requests, batches and
     saturation (queue-depth peak, deadline- vs size-fired dispatches),
     surfaced service-lifetime through ``fleet_summary()["latency"]``.
 
@@ -42,13 +42,9 @@ import dataclasses
 import threading
 import time
 from concurrent.futures import Future
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional
 
-import numpy as np
-
-# Latency sample window for the running p50/p99 (lifetime max is exact).
-# Bounded so a long-lived service never grows host memory with traffic.
-LATENCY_WINDOW = 4096
+from ..spans import span
 
 
 @dataclasses.dataclass
@@ -88,6 +84,7 @@ class _Batch:
     subs: List[_Submission]
     cause: str
     t_close: float
+    seq: int                       # dispatch order, from 0
 
 
 class ServingFrontend:
@@ -135,8 +132,7 @@ class ServingFrontend:
         self._flush_requested = False
         self._batcher_done = not self.threaded
         self._rid = 0
-        self._samples: "collections.deque[float]" = collections.deque(
-            maxlen=LATENCY_WINDOW)
+        self._seq = 0                               # guarded by _cv
         self._threads: List[threading.Thread] = []
         if self.threaded:
             for name, target in (("frontend-batcher", self._batch_loop),
@@ -257,7 +253,9 @@ class ServingFrontend:
                                self._pending[self.max_batch:])
         if not self._pending:
             self._flush_requested = False
-        return _Batch(subs, cause, self.clock())
+        batch = _Batch(subs, cause, self.clock(), self._seq)
+        self._seq += 1
+        return batch
 
     def _batch_loop(self) -> None:
         """Batcher thread: owns deadline timing, closes batches, and —
@@ -292,7 +290,8 @@ class ServingFrontend:
                 # Off-lock staging: getters inside prestage take the
                 # cache's own lock; holding our condition lock here
                 # would serialize staging against submit/dispatch.
-                self.service.prestage([s.query for s in unstaged])
+                with span("frontend.prestage", q=len(unstaged)):
+                    self.service.prestage([s.query for s in unstaged])
                 now = self.clock()
                 with self._cv:
                     for s in unstaged:
@@ -335,67 +334,61 @@ class ServingFrontend:
         (run_batch's own contract makes that an engine bug, not a
         query-shaped problem).
         """
-        if self.prefetch and not self.threaded:
-            # Inline mode has no staging thread: prestage right before
-            # the launch so the getters still hit resident planes.
-            self.service.prestage(
-                [s.query for s in batch.subs if not s.staged])
-            now = self.clock()
-            for s in batch.subs:
-                if not s.staged:
-                    s.staged = True
-                    s.t_staged = now
-        t_launch = self.clock()
-        try:
-            reports = self.service.run_batch(
-                [s.query for s in batch.subs], self.pipeline)
-        except BaseException as exc:  # noqa: BLE001 — futures must resolve
-            for s in batch.subs:
-                s.future.set_exception(exc)
-            raise
-        t_done = self.clock()
-        lat_ms: List[float] = []
-        responses: List[FrontendResponse] = []
-        for s, rep in zip(batch.subs, reports):
-            ms = (t_done - s.t_submit) * 1e3
-            lat_ms.append(ms)
-            responses.append(FrontendResponse(
-                rid=s.rid, report=rep, cause=batch.cause,
-                timestamps=dict(queued=s.t_submit, staged=s.t_staged,
-                                dispatched=batch.t_close, launched=t_launch,
-                                done=t_done),
-                queue_ms=(batch.t_close - s.t_submit) * 1e3,
-                latency_ms=ms, queue_depth=s.queue_depth))
-        block = self._account(batch, lat_ms)
-        for rep in reports:
-            # run_batch gave each report its own counters copy; the
-            # batch's latency block joins the other per-batch sections
-            rep.counters["latency"] = dict(block)
-        for s, resp in zip(batch.subs, responses):
-            s.future.set_result(resp)
+        with span("frontend.execute", batch=batch.seq, q=len(batch.subs),
+                  first=batch.subs[0].rid, last=batch.subs[-1].rid,
+                  cause=batch.cause):
+            if self.prefetch and not self.threaded:
+                # Inline mode has no staging thread: prestage right
+                # before the launch so the getters still hit resident
+                # planes.
+                with span("frontend.prestage", q=len(batch.subs)):
+                    self.service.prestage(
+                        [s.query for s in batch.subs if not s.staged])
+                now = self.clock()
+                for s in batch.subs:
+                    if not s.staged:
+                        s.staged = True
+                        s.t_staged = now
+            t_launch = self.clock()
+            try:
+                reports = self.service.run_batch(
+                    [s.query for s in batch.subs], self.pipeline)
+            except BaseException as exc:  # noqa: BLE001 — futures resolve
+                for s in batch.subs:
+                    s.future.set_exception(exc)
+                raise
+            t_done = self.clock()
+            responses: List[FrontendResponse] = []
+            for s, rep in zip(batch.subs, reports):
+                responses.append(FrontendResponse(
+                    rid=s.rid, report=rep, cause=batch.cause,
+                    timestamps=dict(queued=s.t_submit, staged=s.t_staged,
+                                    dispatched=batch.t_close,
+                                    launched=t_launch, done=t_done),
+                    queue_ms=(batch.t_close - s.t_submit) * 1e3,
+                    latency_ms=(t_done - s.t_submit) * 1e3,
+                    queue_depth=s.queue_depth))
+            block = self._account(batch)
+            for rep in reports:
+                # run_batch gave each report its own counters copy; the
+                # batch's latency block joins the other per-batch sections
+                rep.counters["latency"] = dict(block)
+            for s, resp in zip(batch.subs, responses):
+                s.future.set_result(resp)
 
-    def _account(self, batch: _Batch, lat_ms: Sequence[float]) -> dict:
+    def _account(self, batch: _Batch) -> dict:
         """Fold one batch into the service-lifetime latency block and
         return the per-batch ``counters["latency"]`` section (every key
         declared in ``COUNTER_REGISTRY`` — CL006)."""
-        p50, p99 = np.percentile(np.asarray(lat_ms), (50.0, 99.0))
         staged = sum(1 for s in batch.subs if s.t_staged is not None)
         block = dict(requests=len(batch.subs), batches=1,
                      deadline_fired=0, size_fired=0, flush_fired=0,
                      queue_depth_peak=max(s.queue_depth for s in batch.subs),
-                     prefetches=staged,
-                     p50_ms=float(p50), p99_ms=float(p99),
-                     max_ms=float(max(lat_ms)))
+                     prefetches=staged)
         block[batch.cause + "_fired"] = 1
         with self._lock:
             lat = self.service.latency
             lat["batches"] += 1
             lat[batch.cause + "_fired"] += 1
             lat["prefetches"] += staged
-            self._samples.extend(lat_ms)
-            window = np.asarray(self._samples)
-            w50, w99 = np.percentile(window, (50.0, 99.0))
-            lat["p50_ms"] = float(w50)
-            lat["p99_ms"] = float(w99)
-            lat["max_ms"] = max(lat["max_ms"], float(max(lat_ms)))
         return block

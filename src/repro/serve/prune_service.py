@@ -80,6 +80,7 @@ from ..core.predicate_cache import TableVersion
 from ..core.prune_filter import eval_tv, extract_ranges
 from ..core.prune_join import DEFAULT_ENUM_LIMIT, BuildSummary
 from ..kernels import ops as kops
+from ..spans import span
 from .resilience import (DegradationLadder, new_latency_counters,
                          new_resilience_counters, resilience_delta,
                          resilience_snapshot)
@@ -536,25 +537,27 @@ class PruningService:
             # Pin scope: served verdict rows stay resident while the
             # misses' launch consumes the stat planes.
             with self.cache.pin_scope():
-                for i, (ck, pred) in enumerate(zip(u_keys, u_preds)):
-                    row = self.cache.verdict_plane(table, pred, ck)
-                    if row is None:
-                        miss.append(i)
-                    else:
-                        rows[i] = row
-                self.resilience["verdict_hits"] += len(u_keys) - len(miss)
-                self.resilience["verdict_misses"] += len(miss)
+                with span("filter.verdict", q=len(u_keys)):
+                    for i, (ck, pred) in enumerate(zip(u_keys, u_preds)):
+                        row = self.cache.verdict_plane(table, pred, ck)
+                        if row is None:
+                            miss.append(i)
+                        else:
+                            rows[i] = row
+                    self.resilience["verdict_hits"] += len(u_keys) - len(miss)
+                    self.resilience["verdict_misses"] += len(miss)
                 if miss:
                     tv_rows, rung = self.ladder.execute(self._filter_rungs(
                         table, [u_ranges[i] for i in miss],
                         [u_preds[i] for i in miss]))
                     if tv_rows is not None:
-                        for mi, tv in zip(miss, tv_rows):
-                            row = np.asarray(tv, dtype=np.int8)
-                            rows[mi] = row
-                            if rung != "passthrough" and admit[mi]:
-                                self.cache.verdict_record(
-                                    table, u_preds[mi], u_keys[mi], row)
+                        with span("filter.verdict", q=len(miss)):
+                            for mi, tv in zip(miss, tv_rows):
+                                row = np.asarray(tv, dtype=np.int8)
+                                rows[mi] = row
+                                if rung != "passthrough" and admit[mi]:
+                                    self.cache.verdict_record(
+                                        table, u_preds[mi], u_keys[mi], row)
             return rows
 
         u_rows, _rung = self.ladder.execute(
@@ -577,25 +580,27 @@ class PruningService:
         # id(table) -> (table, [(query idx, scan name, ranges, pred), ...])
         groups: Dict[int, Tuple[object, list]] = {}
         fallbacks: List[Tuple[int, str, object]] = []
-        for qi, q in enumerate(queries):
-            for name, spec in q.scans.items():
-                self.counters.scans += 1
-                if isinstance(spec.pred, E.TruePred):
-                    results[qi][name] = live_full_scan(spec.table)
-                    continue
-                try:
-                    ranges = extract_ranges(spec.pred, spec.table.stats)
-                except Exception:
-                    # malformed spec (unknown column / bad literal):
-                    # isolate to this scan, keep the batch on course
-                    self.resilience["errors"] += 1
-                    results[qi][name] = self._passthrough_set(spec.table)
-                    continue
-                if ranges is None:
-                    fallbacks.append((qi, name, spec))
-                    continue
-                groups.setdefault(id(spec.table), (spec.table, []))[1].append(
-                    (qi, name, ranges, spec.pred))
+        with span("filter.plan", q=len(queries)):
+            for qi, q in enumerate(queries):
+                for name, spec in q.scans.items():
+                    self.counters.scans += 1
+                    if isinstance(spec.pred, E.TruePred):
+                        results[qi][name] = live_full_scan(spec.table)
+                        continue
+                    try:
+                        ranges = extract_ranges(spec.pred, spec.table.stats)
+                    except Exception:
+                        # malformed spec (unknown column / bad literal):
+                        # isolate to this scan, keep the batch on course
+                        self.resilience["errors"] += 1
+                        results[qi][name] = self._passthrough_set(spec.table)
+                        continue
+                    if ranges is None:
+                        fallbacks.append((qi, name, spec))
+                        continue
+                    groups.setdefault(id(spec.table),
+                                      (spec.table, []))[1].append(
+                        (qi, name, ranges, spec.pred))
         for table, jobs in groups.values():
             if self.verdict_cache:
                 rows = self._verdict_group(table, jobs)
@@ -609,23 +614,27 @@ class PruningService:
             # scan set once per unique row, give each query its own
             # ScanSet over the shared (read-only) arrays
             memo: Dict[int, ScanSet] = {}
-            for (qi, name, _ranges, _pred), tv in zip(jobs, rows):
-                if tv is None:
-                    results[qi][name] = self._passthrough_set(table)
-                    continue
-                ss = memo.get(id(tv))
-                if ss is None:
-                    memo[id(tv)] = ss = self._scan_set(tv, table)
-                results[qi][name] = ScanSet(ss.part_ids, ss.match)
-        for qi, name, spec in fallbacks:
-            self.counters.bump("filter", fallbacks=1)
-            try:
-                tv = eval_tv(spec.pred, spec.table.stats)
-            except Exception:
-                self.resilience["errors"] += 1
-                results[qi][name] = self._passthrough_set(spec.table)
-                continue
-            results[qi][name] = self._scan_set(tv, spec.table)
+            with span("filter.assemble", q=len(jobs),
+                      P=table.stats.num_partitions):
+                for (qi, name, _ranges, _pred), tv in zip(jobs, rows):
+                    if tv is None:
+                        results[qi][name] = self._passthrough_set(table)
+                        continue
+                    ss = memo.get(id(tv))
+                    if ss is None:
+                        memo[id(tv)] = ss = self._scan_set(tv, table)
+                    results[qi][name] = ScanSet(ss.part_ids, ss.match)
+        if fallbacks:
+            with span("filter.host_eval", q=len(fallbacks)):
+                for qi, name, spec in fallbacks:
+                    self.counters.bump("filter", fallbacks=1)
+                    try:
+                        tv = eval_tv(spec.pred, spec.table.stats)
+                    except Exception:
+                        self.resilience["errors"] += 1
+                        results[qi][name] = self._passthrough_set(spec.table)
+                        continue
+                    results[qi][name] = self._scan_set(tv, spec.table)
         return results
 
     # -- join stage ---------------------------------------------------------
@@ -927,84 +936,91 @@ class PruningService:
         from ..core.flow import PruningPipeline
         if pipeline is None:
             pipeline = PruningPipeline(filter_mode="device", service=self)
-        # Only batch device stages when the pipeline itself declares the
-        # device path — a host/adaptive pipeline keeps its own semantics.
-        device = not pipeline.adaptive and pipeline.filter_mode == "device"
-        before = self.counters.snapshot()
-        before_staging = self.cache.staging_snapshot()
-        before_memory = self.cache.memory.snapshot()
-        before_res = resilience_snapshot(self.resilience)
-        before_integrity = self.cache.integrity_snapshot()
-        # satellite: per-query spec validation — one malformed query
-        # becomes one passthrough report, the rest stay on the fast path
-        invalid: Dict[int, object] = {}
-        valid: List[Tuple[int, object]] = []
-        for i, q in enumerate(queries):
+        with span("run_batch", q=len(queries)):
+            # Only batch device stages when the pipeline itself declares the
+            # device path — a host/adaptive pipeline keeps its own semantics.
+            device = not pipeline.adaptive and pipeline.filter_mode == "device"
+            with span("account"):
+                before = self.counters.snapshot()
+                before_staging = self.cache.staging_snapshot()
+                before_memory = self.cache.memory.snapshot()
+                before_res = resilience_snapshot(self.resilience)
+                before_integrity = self.cache.integrity_snapshot()
+            # satellite: per-query spec validation — one malformed query
+            # becomes one passthrough report, the rest stay on the fast path
+            invalid: Dict[int, object] = {}
+            valid: List[Tuple[int, object]] = []
+            with span("validate", q=len(queries)):
+                for i, q in enumerate(queries):
+                    try:
+                        self._validate_query(q)
+                    except Exception:
+                        self.resilience["errors"] += 1
+                        invalid[i] = q
+                    else:
+                        valid.append((i, q))
+                states = [pipeline.make_state(q) for _, q in valid]
             try:
-                self._validate_query(q)
-            except Exception:
-                self.resilience["errors"] += 1
-                invalid[i] = q
-            else:
-                valid.append((i, q))
-        states = [pipeline.make_state(q) for _, q in valid]
-        try:
-            for tech in pipeline.techniques:
-                tech.run_batch(pipeline, states,
-                               service=self if device else None)
-            good = [pipeline.finish(s) for s in states]
-        except Exception as exc:
-            # Last-resort guard: something outside the ladder's reach
-            # broke the batched drive (a host-stage bug, a summary raise).
-            # Salvage per query; a query that still fails degrades to a
-            # passthrough report instead of taking the batch down.
-            self.resilience["salvaged_batches"] += 1
-            self.ladder.last_errors["run_batch"] = exc
-            good = []
-            for _i, q in valid:
-                try:
-                    good.append(pipeline.run(q))
-                except Exception:
-                    self.resilience["errors"] += 1
-                    good.append(self._passthrough_report(pipeline, q))
-        reports: List = [None] * len(queries)
-        for (i, _q), rep in zip(valid, good):
-            reports[i] = rep
-        for i, q in invalid.items():
-            reports[i] = self._passthrough_report(pipeline, q)
-        delta = ServiceCounters.delta(before, self.counters.snapshot())
-        after_staging = self.cache.staging_snapshot()
-        staging = {k: after_staging[k] - before_staging[k]
-                   for k in after_staging}
-        memory = PlaneMemoryManager.delta(before_memory,
-                                          self.cache.memory.snapshot())
-        res = resilience_delta(before_res,
-                               resilience_snapshot(self.resilience))
-        after_integrity = self.cache.integrity_snapshot()
-        integrity = {k: after_integrity[k] - before_integrity[k]
-                     for k in after_integrity}
-        # PlaneEpoch per table touched by the batch: what the launches
-        # actually ran against (version, live count, capacity) — the
-        # check that a delta-staged batch served the same table state a
-        # fresh restage would.
-        planes: Dict[str, dict] = {}
-        for q in queries:
-            for spec in q.scans.values():
-                epoch = self.cache.plane_epoch(spec.table)
-                if epoch is not None:
-                    planes[spec.table.name] = dataclasses.asdict(epoch)
-        for r in reports:
-            # each report owns its copy — mutating one never leaks
-            r.counters = {**delta,
-                          "technique": {k: dict(v)
-                                        for k, v in delta["technique"].items()},
-                          "staging": dict(staging),
-                          "memory": dict(memory),
-                          "resilience": {**res,
-                                         "demotions": dict(res["demotions"])},
-                          "integrity": dict(integrity),
-                          "planes": {k: dict(v) for k, v in planes.items()}}
-        return reports
+                for tech in pipeline.techniques:
+                    with span("technique." + tech.name, q=len(states)):
+                        tech.run_batch(pipeline, states,
+                                       service=self if device else None)
+                with span("finish", q=len(states)):
+                    good = [pipeline.finish(s) for s in states]
+            except Exception as exc:
+                # Last-resort guard: something outside the ladder's reach
+                # broke the batched drive (a host-stage bug, a summary raise).
+                # Salvage per query; a query that still fails degrades to a
+                # passthrough report instead of taking the batch down.
+                self.resilience["salvaged_batches"] += 1
+                self.ladder.last_errors["run_batch"] = exc
+                good = []
+                for _i, q in valid:
+                    try:
+                        good.append(pipeline.run(q))
+                    except Exception:
+                        self.resilience["errors"] += 1
+                        good.append(self._passthrough_report(pipeline, q))
+            reports: List = [None] * len(queries)
+            for (i, _q), rep in zip(valid, good):
+                reports[i] = rep
+            for i, q in invalid.items():
+                reports[i] = self._passthrough_report(pipeline, q)
+            with span("account"):
+                delta = ServiceCounters.delta(before, self.counters.snapshot())
+                after_staging = self.cache.staging_snapshot()
+                staging = {k: after_staging[k] - before_staging[k]
+                           for k in after_staging}
+                memory = PlaneMemoryManager.delta(before_memory,
+                                                  self.cache.memory.snapshot())
+                res = resilience_delta(before_res,
+                                       resilience_snapshot(self.resilience))
+                after_integrity = self.cache.integrity_snapshot()
+                integrity = {k: after_integrity[k] - before_integrity[k]
+                             for k in after_integrity}
+                # PlaneEpoch per table touched by the batch: what the launches
+                # actually ran against (version, live count, capacity) — the
+                # check that a delta-staged batch served the same table state
+                # a fresh restage would.
+                planes: Dict[str, dict] = {}
+                for q in queries:
+                    for spec in q.scans.values():
+                        epoch = self.cache.plane_epoch(spec.table)
+                        if epoch is not None:
+                            planes[spec.table.name] = dataclasses.asdict(epoch)
+                for r in reports:
+                    # each report owns its copy — mutating one never leaks
+                    r.counters = {**delta,
+                                  "technique": {k: dict(v) for k, v
+                                                in delta["technique"].items()},
+                                  "staging": dict(staging),
+                                  "memory": dict(memory),
+                                  "resilience": {**res, "demotions":
+                                                 dict(res["demotions"])},
+                                  "integrity": dict(integrity),
+                                  "planes": {k: dict(v)
+                                             for k, v in planes.items()}}
+            return reports
 
     def run_fleet(self, batches: Sequence[Sequence], pipeline=None) -> List:
         """The fleet-scale entry point: a *many-table* workload — a

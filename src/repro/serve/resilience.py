@@ -46,6 +46,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core.device_stats import PlaneIntegrityError  # noqa: F401  re-export
+from ..spans import span
 
 # The ordered fallback chain.  A launch enters at the highest rung its
 # configuration supports (the verdict rung only when the service's
@@ -91,7 +92,6 @@ COUNTER_REGISTRY = frozenset({
     # the lifetime block through fleet_summary()["latency"])
     "latency", "requests", "batches", "deadline_fired", "size_fired",
     "flush_fired", "queue_depth_peak", "prefetches",
-    "p50_ms", "p99_ms", "max_ms",
 })
 
 
@@ -112,12 +112,12 @@ def new_latency_counters() -> dict:
     flush_fired
     queue_depth_peak        deepest pending queue observed at any submit
     prefetches              staging prefetches overlapped with launches
-    p50_ms / p99_ms /       end-to-end latency percentiles over the
-    max_ms                  retained sample window (max is lifetime-true)
+
+    Per-request latency is each ``FrontendResponse``'s own
+    (``latency_ms``, ``queue_ms``, ``timestamps``).
     """
     return dict(requests=0, batches=0, deadline_fired=0, size_fired=0,
-                flush_fired=0, queue_depth_peak=0, prefetches=0,
-                p50_ms=0.0, p99_ms=0.0, max_ms=0.0)
+                flush_fired=0, queue_depth_peak=0, prefetches=0)
 
 
 def resilience_snapshot(c: dict) -> dict:
@@ -309,7 +309,8 @@ class DegradationLadder:
             attempt = 0
             while True:
                 try:
-                    result = thunk()
+                    with span("rung." + name):
+                        result = thunk()
                 except Exception as exc:      # noqa: BLE001 — the whole point
                     last_exc = exc
                     if attempt >= self.policy.retries or self._expired(start):
@@ -324,7 +325,8 @@ class DegradationLadder:
                         c["deadline_hits"] += 1
                         break
                     c["retries"] += 1
-                    self.sleep(delay)
+                    with span("backoff"):
+                        self.sleep(delay)
                     attempt += 1
                 else:
                     if name == "passthrough":

@@ -343,6 +343,35 @@ class TestTraceSafety:
         msgs = [f.message for f in findings if f.rule == "CL005"]
         assert any("float" in m for m in msgs)
 
+    def test_flags_profiler_span_in_jitted_fn_and_kernel_body(self):
+        findings = lint("src/repro/kernels/op.py", """\
+            @jax.jit
+            def f(x):
+                with span("dispatch"):
+                    return x + 1
+
+            def _scan_kernel(x_ref, o_ref):
+                with jax.profiler.TraceAnnotation("prune.kernel"):
+                    o_ref[...] = x_ref[...]
+            """)
+        msgs = [f.message for f in findings if f.rule == "CL005"]
+        assert any("'span'" in m for m in msgs)
+        assert any("jax.profiler.TraceAnnotation" in m for m in msgs)
+
+    def test_span_in_host_wrapper_passes(self):
+        findings = lint("src/repro/kernels/op.py", """\
+            @jax.jit
+            def f(x):
+                return x + 1
+
+            def wrapper(x):
+                with span("dispatch"):
+                    out = f(x)
+                with span("d2h"):
+                    return np.asarray(out)
+            """)
+        assert "CL005" not in rules(findings)
+
     def test_static_argnames_and_closure_config_pass(self):
         findings = lint("src/repro/kernels/op.py", """\
             @functools.partial(jax.jit, static_argnames=("interpret", "k"))

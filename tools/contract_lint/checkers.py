@@ -451,7 +451,8 @@ class TraceSafetyChecker:
     """Inside Pallas kernel bodies and jitted functions: no Python
     ``if``/``while`` on traced parameters (static_argnames are exempt),
     no ``float()``/``int()``/``bool()`` on traced values, no ``.item()``,
-    and no nondeterminism (``time.*``, unseeded ``np.random.*``).
+    no nondeterminism (``time.*``, unseeded ``np.random.*``), and no
+    profiler spans (``span``, ``TraceAnnotation``, ``jax.profiler.*``).
 
     Traced functions are found syntactically: defs whose name ends in
     ``_kernel``, defs passed (by name) as the first argument of
@@ -468,6 +469,7 @@ class TraceSafetyChecker:
     SHARD = ("shard_map", "jax.experimental.shard_map.shard_map")
     NONDET_PREFIXES = ("time.", "np.random.", "numpy.random.", "random.")
     NONDET_ALLOWED = ("default_rng", "Generator", "SeedSequence", "PRNGKey")
+    SPAN_CALLS = ("span", "spans.span", "TraceAnnotation")
 
     # -- traced-function discovery ---------------------------------------
 
@@ -638,6 +640,16 @@ class TraceSafetyChecker:
                         self, mod, node,
                         "`.item()` inside a traced function forces a "
                         "device sync / concretization error under jit",
+                        ctx))
+                if d is not None and (
+                        d in self.SPAN_CALLS
+                        or d.endswith(".TraceAnnotation")
+                        or d.startswith("jax.profiler.")):
+                    out.append(_finding(
+                        self, mod, node,
+                        f"profiler span '{d}' inside a traced function — "
+                        f"it runs once at trace time, not per launch; "
+                        f"open host spans in the wrapper that calls it",
                         ctx))
                 if d is not None:
                     for prefix in self.NONDET_PREFIXES:
