@@ -315,10 +315,12 @@ class JoinTechnique(Technique):
                                    ndv_limit=pipe.join_ndv_limit)
 
     def _apply(self, pipe, state, summary: BuildSummary,
-               hit: Optional[np.ndarray]) -> None:
+               hit: Optional[np.ndarray], service=None) -> None:
         """Overlap + prune the probe scan; ``hit`` is the device result
         [P] — distinct-key overlap or Bloom enumeration, per the summary
-        kind (None -> host matcher)."""
+        kind (None -> host matcher).  Last reader of the summary: a Bloom
+        summary's filter is ``built`` or ``deferred`` (never built) from
+        here on, counted under ``join_bloom`` when a service runs."""
         q = state.query
         scan = state.scan_sets[q.join.probe]
         with span("join.apply", P=len(scan)):
@@ -327,37 +329,40 @@ class JoinTechnique(Technique):
             res = prune_probe(
                 scan, q.scans[q.join.probe].table.stats,
                 q.join.probe_key, summary,
-                distinct_hit=over if summary.distinct is not None else None,
-                bloom_hit=over if summary.bloom is not None else None,
+                distinct_hit=over if summary.kind == "distinct" else None,
+                bloom_hit=over if summary.kind == "bloom" else None,
             )
+        detail = dict(
+            by_range=res.pruned_by_range,
+            by_distinct=res.pruned_by_distinct,
+            by_bloom=res.pruned_by_bloom,
+            summary_bytes=summary.size_bytes,
+            summary_kind=summary.kind,
+            path="device" if hit is not None else "host",
+        )
+        if summary.kind == "bloom":
+            built = summary.bloom_built
+            detail["bloom"] = "built" if built else "deferred"
+            if service is not None:
+                service.counters.bump("join_bloom", built=int(built),
+                                      deferred=int(not built))
         state.scan_sets[q.join.probe] = res.scan
         state.per_scan[q.join.probe]["join"] = TechniqueReport(
             res.partitions_before, res.partitions_after,
-            applied=True,
-            detail=dict(
-                by_range=res.pruned_by_range,
-                by_distinct=res.pruned_by_distinct,
-                by_bloom=res.pruned_by_bloom,
-                summary_bytes=summary.size_bytes,
-                summary_kind=(
-                    "distinct" if summary.distinct is not None
-                    else "bloom" if summary.bloom is not None else "empty"
-                ),
-                path="device" if hit is not None else "host",
-            ),
-        )
+            applied=True, detail=detail)
 
     def run(self, pipe, state):
         summary = self._summarize(pipe, state)
         if summary is None:
             return
-        hit = None
+        hit = service = None
         if pipe.filter_mode == "device" and not pipe.adaptive:
             q = state.query
-            hit = pipe.device_service().join_hit(
+            service = pipe.device_service()
+            hit = service.join_hit(
                 q.scans[q.join.probe].table, q.join.probe_key, summary,
                 part_ids=state.scan_sets[q.join.probe].part_ids)
-        self._apply(pipe, state, summary, hit)
+        self._apply(pipe, state, summary, hit, service)
 
     def run_batch(self, pipe, states, service=None):
         if service is None:
@@ -378,7 +383,7 @@ class JoinTechnique(Technique):
                                                 q.join.probe_key):
                 host_jobs.append((st, summary))
                 continue
-            g = groups if summary.distinct is not None else bloom_groups
+            g = groups if summary.kind == "distinct" else bloom_groups
             g.setdefault(
                 (id(table), q.join.probe_key),
                 (table, q.join.probe_key, []))[2].append((st, summary))
@@ -393,7 +398,7 @@ class JoinTechnique(Technique):
                 # is the stage's exact terminal rung
                 hits = [None] * len(members)
             for (st, summary), hit in zip(members, hits):
-                self._apply(pipe, st, summary, hit)
+                self._apply(pipe, st, summary, hit, service)
         for table, key_col, members in bloom_groups.values():
             hits = service.bloom_hit_batch(
                 table, key_col, [s for _, s in members],
@@ -402,13 +407,13 @@ class JoinTechnique(Technique):
             if hits is None:
                 hits = [None] * len(members)
             for (st, summary), hit in zip(members, hits):
-                self._apply(pipe, st, summary, hit)
+                self._apply(pipe, st, summary, hit, service)
         for st, summary in host_jobs:
             if not summary.empty:
                 service.counters.bump(
-                    "join_bloom" if summary.bloom is not None else self.name,
+                    "join_bloom" if summary.kind == "bloom" else self.name,
                     fallbacks=1)
-            self._apply(pipe, st, summary, None)
+            self._apply(pipe, st, summary, None, service)
 
 
 class TopKTechnique(Technique):

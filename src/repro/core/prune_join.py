@@ -1,7 +1,8 @@
 """JOIN pruning: coarse-grained sideways information passing (paper Sec. 6).
 
 Four steps, exactly the paper's:
-  (1) summarize the build side's join-key values during the build phase,
+  (1) summarize the build side's join-key values during the build phase:
+      the min/max, the distinct count and the summary's kind and size,
   (2) ship the summary to the probe side (size-bounded — it crosses the
       network in a distributed setting),
   (3) match the summary against probe-side partitions' min/max metadata,
@@ -21,6 +22,12 @@ Summary structure ("balance between accuracy and storage cost"):
     enumeration batched (Q filters x P partitions) against the resident
     enumeration plane, and ``prune_probe`` accepts its result via
     ``bloom_hit`` exactly like ``distinct_hit``.
+
+The Bloom filter itself is materialized from the sorted distinct keys
+when step 3 first reads it — the host's narrow-range enumeration, or the
+batched device probe's launch.  A probe side with no enumerable partition
+never builds it; what ships (``size_bytes``) and what prunes are the
+same either way.
 
 The technique is probabilistic in the paper's sense: it may *miss* a
 prunable partition (Bloom false positives) but never prunes a partition
@@ -73,16 +80,21 @@ def _probe_coords(keys: np.ndarray, n_blocks: int):
     return block, words, bits
 
 
+def bloom_blocks(n_keys: int, bits_per_key: int = 16) -> int:
+    """Power-of-two block count of a filter over ``n_keys`` keys."""
+    want_bits = max(n_keys, 1) * bits_per_key
+    n_blocks = 1
+    while n_blocks * BLOCK_WORDS * 32 < want_bits:
+        n_blocks *= 2
+    return n_blocks
+
+
 class BlockedBloom:
     """Register-blocked Bloom filter over int-domain keys."""
 
     def __init__(self, n_keys: int, bits_per_key: int = 16):
-        want_bits = max(n_keys, 1) * bits_per_key
-        n_blocks = 1
-        while n_blocks * BLOCK_WORDS * 32 < want_bits:
-            n_blocks *= 2
-        self.n_blocks = n_blocks
-        self.words = np.zeros(n_blocks * BLOCK_WORDS, dtype=np.uint32)
+        self.n_blocks = bloom_blocks(n_keys, bits_per_key)
+        self.words = np.zeros(self.n_blocks * BLOCK_WORDS, dtype=np.uint32)
 
     @property
     def size_bytes(self) -> int:
@@ -106,18 +118,50 @@ class BlockedBloom:
 
 @dataclasses.dataclass
 class BuildSummary:
-    """What ships from build to probe side (step 2)."""
+    """What ships from build to probe side (step 2).
+
+    ``kind`` is ``"empty"``, ``"distinct"`` (the sorted distinct keys
+    ship) or ``"bloom"`` (a filter of ``n_blocks`` blocks ships).  The
+    filter is built from ``keys`` on first access to ``bloom``; kind
+    tests read ``kind`` and ``n_blocks`` and never build it."""
 
     min: float
     max: float
     count: int
-    distinct: Optional[np.ndarray]      # sorted distinct keys, if NDV small
-    bloom: Optional[BlockedBloom]
-    size_bytes: int
+    kind: str
+    keys: np.ndarray                    # sorted distinct build keys
+    n_blocks: int = 0
+    bits_per_key: int = 16
+    _bloom: Optional[BlockedBloom] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     @property
     def empty(self) -> bool:
         return self.count == 0
+
+    @property
+    def distinct(self) -> Optional[np.ndarray]:
+        """The sorted distinct keys, unless the summary is a Bloom one."""
+        return None if self.kind == "bloom" else self.keys
+
+    @property
+    def bloom(self) -> Optional[BlockedBloom]:
+        if self.kind != "bloom":
+            return None
+        if self._bloom is None:
+            self._bloom = BlockedBloom(self.keys.size, self.bits_per_key)
+            self._bloom.add(self.keys)
+        return self._bloom
+
+    @property
+    def bloom_built(self) -> bool:
+        return self._bloom is not None
+
+    @property
+    def size_bytes(self) -> int:
+        if self.kind == "bloom":
+            return self.n_blocks * BLOCK_WORDS * 4 + 16
+        return int(self.keys.nbytes) + 16
 
 
 def summarize_build(
@@ -133,20 +177,15 @@ def summarize_build(
         # The empty distinct set keeps the key column's dtype: callers
         # (device eligibility, np.isin masks) see the real key domain, not
         # an accidental float64.
-        return BuildSummary(np.inf, -np.inf, 0,
-                            np.zeros(0, dtype=keys.dtype), None, 16)
+        return BuildSummary(np.inf, -np.inf, 0, "empty",
+                            np.zeros(0, dtype=keys.dtype))
     uniq = np.unique(keys)
     if uniq.size <= ndv_limit:
-        return BuildSummary(
-            float(uniq[0]), float(uniq[-1]), int(keys.size),
-            uniq, None, int(uniq.nbytes) + 16,
-        )
-    bloom = BlockedBloom(uniq.size, bits_per_key)
-    bloom.add(uniq)
-    return BuildSummary(
-        float(uniq[0]), float(uniq[-1]), int(keys.size),
-        None, bloom, bloom.size_bytes + 16,
-    )
+        return BuildSummary(float(uniq[0]), float(uniq[-1]), int(keys.size),
+                            "distinct", uniq)
+    return BuildSummary(float(uniq[0]), float(uniq[-1]), int(keys.size),
+                        "bloom", uniq, bloom_blocks(uniq.size, bits_per_key),
+                        bits_per_key)
 
 
 @dataclasses.dataclass
@@ -194,7 +233,7 @@ def prune_probe(
     n_range = int(before - keep.sum())
     n_distinct = n_bloom = 0
 
-    if summary.distinct is not None:
+    if summary.kind == "distinct":
         if distinct_hit is not None:
             hit = np.asarray(distinct_hit, dtype=bool)
         else:
@@ -204,7 +243,7 @@ def prune_probe(
             hit = hi > lo
         n_distinct = int((keep & ~hit).sum())
         keep &= hit
-    elif summary.bloom is not None:
+    elif summary.kind == "bloom":
         if bloom_hit is not None:
             hit = np.asarray(bloom_hit, dtype=bool)
             n_bloom = int((keep & ~hit).sum())

@@ -120,15 +120,21 @@ class ServiceCounters:
     host_fallbacks: int = 0    # host fallbacks, all techniques
     sharded_launches: int = 0  # launches that ran partition-sharded
     tree_launches: int = 0     # launches that ran the hierarchical path
-    # per-technique attribution: {'filter': {'launches': n, 'fallbacks': m}}
+    # per-technique attribution: {'filter': {'launches': n, 'fallbacks': m}};
+    # 'join_bloom' also counts Bloom summaries whose filter a matcher
+    # read ('built') or that finished the join stage without it ('deferred')
     technique: Dict[str, Dict[str, int]] = dataclasses.field(
         default_factory=dict)
 
     def bump(self, tech: str, launches: int = 0, fallbacks: int = 0,
-             sharded: int = 0, tree: int = 0) -> None:
+             sharded: int = 0, tree: int = 0, built: int = 0,
+             deferred: int = 0) -> None:
         t = self.technique.setdefault(tech, dict(launches=0, fallbacks=0))
         t["launches"] += launches
         t["fallbacks"] += fallbacks
+        if built or deferred:
+            t["built"] = t.get("built", 0) + built
+            t["deferred"] = t.get("deferred", 0) + deferred
         self.launches += launches
         self.host_fallbacks += fallbacks
         self.sharded_launches += sharded
@@ -148,9 +154,8 @@ class ServiceCounters:
         out = {k: after[k] - before[k]
                for k in ("queries", "scans", "launches", "host_fallbacks",
                          "sharded_launches", "tree_launches")}
-        zero = dict(launches=0, fallbacks=0)
         out["technique"] = {
-            t: {f: v - before["technique"].get(t, zero)[f]
+            t: {f: v - before["technique"].get(t, {}).get(f, 0)
                 for f, v in fields.items()}
             for t, fields in after["technique"].items()}
         return out
@@ -657,13 +662,13 @@ class PruningService:
         """
         if summary.empty:
             return False
-        if summary.distinct is not None:
+        if summary.kind == "distinct":
             d32 = np.asarray(summary.distinct,
                              dtype=np.float64).astype(np.float32)
             return bool(np.isfinite(d32).all())
-        if summary.bloom is None or table is None or key_col is None:
+        if summary.kind != "bloom" or table is None or key_col is None:
             return False
-        if summary.bloom.n_blocks > kops.BLOOM_MAX_BLOCKS:
+        if summary.n_blocks > kops.BLOOM_MAX_BLOCKS:
             return False
         if table.stats.column(key_col).kind == "float":
             return False
@@ -769,11 +774,11 @@ class PruningService:
         if not self.join_device_eligible(summary, table, key_col):
             if not summary.empty:
                 self.counters.bump(
-                    "join_bloom" if summary.bloom is not None else "join",
+                    "join_bloom" if summary.kind == "bloom" else "join",
                     fallbacks=1)
             return None
         pid = None if part_ids is None else [part_ids]
-        if summary.distinct is not None:
+        if summary.kind == "distinct":
             hit = self.join_hit_batch(table, key_col, [summary],
                                       part_ids=pid)
         else:

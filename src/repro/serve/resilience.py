@@ -83,8 +83,11 @@ COUNTER_REGISTRY = frozenset({
     "verdict_hits", "verdict_misses", "verdict_deduped", "verdict_repairs",
     # plane-integrity counters (core.device_stats.DeviceStatsCache)
     "verifications", "checksum_failures", "quarantines",
-    # per-technique attribution (ServiceCounters.bump / .technique)
+    # per-technique attribution (ServiceCounters.bump / .technique);
+    # built / deferred: Bloom summaries whose filter was or was never
+    # materialized by the join stage (join_bloom only)
     "filter", "join", "join_bloom", "topk", "launches", "fallbacks",
+    "built", "deferred",
     # report sections attached to each batch (PruningService.run_batch)
     "technique", "staging", "memory", "resilience", "integrity", "planes",
     # latency/SLO counters (new_latency_counters; serve.frontend attaches

@@ -3,6 +3,7 @@
 import warnings
 
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -180,3 +181,54 @@ class TestProbePruning:
             v, _ = ctx.col("k")
             if np.isin(v, build).any():
                 assert p in kept, f"pruned joinable partition {p}"
+
+
+def _probe_keys(layout, rng):
+    """wide: TPC-H-like keys uniform over 2^33, so every partition's key
+    range is far wider than the enumeration limit.  narrow: clustered
+    keys, sorted, each partition ~800 values wide."""
+    if layout == "wide":
+        return rng.integers(0, 2**33, 4096)
+    return np.sort(rng.integers(0, 100_000, 8192))
+
+
+class TestLazyBloom:
+    @pytest.mark.parametrize("layout", ["wide", "narrow"])
+    def test_lazy_filter_prunes_as_the_eager_one(self, layout):
+        """The filter is built on first read: a summary whose filter was
+        built at once prunes the very same partitions, and ships the same
+        size.  A probe side with no enumerable partition never builds it;
+        an enumerable one builds the words BlockedBloom builds."""
+        rng = np.random.default_rng(31)
+        probe = _probe_keys(layout, rng)
+        tbl = _probe_table(probe, rows_per_partition=64)
+        if layout == "wide":
+            build = np.concatenate([probe[::3], rng.integers(0, 2**33, 3000)])
+        else:   # keys in every other 5,000-wide block: the gaps prune
+            build = rng.integers(0, 100_000, 20_000)
+            build = build[(build // 5000) % 2 == 0]
+        uniq = np.unique(build)
+        assert uniq.size > 4096
+        lazy = summarize_build(build)
+        eager = summarize_build(build)
+        assert eager.bloom is not None and eager.bloom_built
+        assert lazy.kind == eager.kind == "bloom" and not lazy.bloom_built
+        assert lazy.n_blocks == BlockedBloom(uniq.size).n_blocks
+        assert lazy.size_bytes == eager.bloom.size_bytes + 16
+
+        scan = ScanSet.full(tbl.num_partitions)
+        got = prune_probe(scan, tbl.stats, "k", lazy)
+        want = prune_probe(scan, tbl.stats, "k", eager)
+        np.testing.assert_array_equal(got.scan.part_ids, want.scan.part_ids)
+        assert ((got.pruned_by_range, got.pruned_by_distinct,
+                 got.pruned_by_bloom)
+                == (want.pruned_by_range, want.pruned_by_distinct,
+                    want.pruned_by_bloom))
+        assert lazy.size_bytes == eager.size_bytes
+        if layout == "wide":
+            assert not lazy.bloom_built
+        else:
+            assert lazy.bloom_built and got.pruned_by_bloom > 0
+            ref = BlockedBloom(uniq.size)
+            ref.add(uniq)
+            np.testing.assert_array_equal(lazy.bloom.words, ref.words)

@@ -578,6 +578,73 @@ class TestBloomEngineParity:
             if np.isin(v, build_keys).any():
                 assert p in kept, f"pruned joinable partition {p}"
 
+    def test_filter_built_only_when_a_matcher_reads_it(self, monkeypatch):
+        """One batch mixing a wide-key Bloom join (keys over 2^33, as
+        TPC-H's order keys: no partition is enumerable and the host
+        matcher keeps it) with narrow-key Bloom joins (one device launch):
+        the wide filter is never built, the launched ones are, and the
+        scan sets equal those of the same batch with every filter built
+        at once."""
+        from repro.core import flow
+        events, users = _engine_tables(seed=27)
+        rng = np.random.default_rng(28)
+        okeys = rng.integers(0, 2**33, 2048)
+        lineitem = Table.build("lineitem", {"okey": okeys},
+                               rows_per_partition=64)
+        orders = Table.build("orders", {
+            "okey": np.sort(okeys[::2]),
+            "flag": rng.integers(0, 4, 1024).astype(np.int64),
+        }, rows_per_partition=64)
+        wide = Query(scans={"l": TableScanSpec(lineitem),
+                            "o": TableScanSpec(orders, E.col("flag") <= 2)},
+                     join=JoinSpec("o", "l", "okey", "okey"))
+        queries = [wide] + _bloom_mixed_workload(events, users, rng, n=6)
+
+        def run():
+            svc = PruningService(mode="ref")
+            pipe = PruningPipeline(filter_mode="device", service=svc,
+                                   join_ndv_limit=8)
+            return svc, svc.run_batch(queries, pipe)
+
+        svc, lazy = run()
+        orig = flow.summarize_build
+
+        def eager(*a, **kw):
+            summary = orig(*a, **kw)
+            summary.bloom                     # build the filter at once
+            return summary
+
+        monkeypatch.setattr(flow, "summarize_build", eager)
+        svc_eager, forced = run()
+
+        joins = [(r.per_scan["l"]["join"] if "l" in r.per_scan
+                  else r.per_scan["e"].get("join")) for r in lazy]
+        assert joins[0].detail["path"] == "host"
+        assert joins[0].detail["bloom"] == "deferred"
+        launched = [j for j in joins[1:]
+                    if j is not None and j.detail["summary_kind"] == "bloom"]
+        assert len(launched) == 2
+        assert all(j.detail["path"] == "device"
+                   and j.detail["bloom"] == "built" for j in launched)
+        assert svc.counters.technique["join_bloom"] == dict(
+            launches=1, fallbacks=1, built=2, deferred=1)
+        assert svc_eager.counters.technique["join_bloom"] == dict(
+            launches=1, fallbacks=1, built=3, deferred=0)
+        for a, b in zip(lazy, forced):
+            for name in a.scan_sets:
+                np.testing.assert_array_equal(a.scan_sets[name].part_ids,
+                                              b.scan_sets[name].part_ids)
+                np.testing.assert_array_equal(a.scan_sets[name].match,
+                                              b.scan_sets[name].match)
+                ja = a.per_scan[name].get("join")
+                jb = b.per_scan[name].get("join")
+                assert (ja is None) == (jb is None)
+                if ja is not None:
+                    assert ({k: v for k, v in ja.detail.items()
+                             if k != "bloom"}
+                            == {k: v for k, v in jb.detail.items()
+                                if k != "bloom"})
+
 
 # ---------------------------------------------------------------------------
 # DML invalidation of the runtime-technique planes
