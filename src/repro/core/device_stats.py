@@ -23,8 +23,8 @@ metadata indexes):
 Precision contract (the single place stats are downcast to f32)
 ---------------------------------------------------------------
 Host metadata is float64; kernels evaluate in float32 for VPU throughput.
-Values outside f32's 24-bit mantissa (e.g. int64 keys > 2**24) cannot be
-represented exactly, so the cast is *widening* and *demoting*:
+Every column has a *primary* row in the ``[C, cap]`` planes, cast by
+widening and demoting:
 
   * partition mins are rounded toward -inf, maxs toward +inf, and query
     lows/highs likewise (lo down, hi up).  Every interval only grows, so
@@ -35,9 +35,30 @@ represented exactly, so the cast is *widening* and *demoting*:
     partition.  Constraints whose lo/hi cast inexactly report
     ``bounds_exact=False`` and the wrapper demotes FULL host-side.
 
-Net effect: int64 keys > 2**24 can only *false-negative* FULL (degrade to
-PARTIAL, costing a scan) and can never *false-positive* NO_MATCH or FULL.
-``tests/test_device_plane.py`` holds the regression test.
+**Wide integer columns are exact.**  An int or dictionary column whose
+finite stats are all integers, reach 2**24 (where the f32 cast starts to
+move them) and stay within 2**53 (where the host's f64 stops being exact)
+is *wide*: Iceberg ``timestamp`` microseconds (~1.7e15), int64 keys.
+Staging decides this from the column's own stats (``stat_layout``); no
+option names it.  A wide column keeps its widened primary row (the tree
+hulls and the join-key overlap read it, and only for NO_MATCH) and adds
+2 or 3 *limb rows* after the primary rows: ``v - base`` (``base`` the
+staged minimum) as a signed top digit and base-2**23 digits below it
+(``stat_limbs``), every one exact in f32 and through the
+``Precision.HIGHEST`` one-hot gather.  The limb rows' ``demote`` is the
+null flag alone.  A constraint on a wide column splits its snapped query
+bounds the same way (``bound_limbs``) and the kernels compare the limbs
+lexicographically, so its NO_MATCH, FULL and so LIMIT cut are
+bit-identical to the f64 host oracle.  Delta replay keeps the layout
+(which columns are wide, their base and digit count); a delta that would
+change it restages.
+
+Net effect: integer columns up to 2**53 prune exactly; floats and integer
+columns beyond 2**53 can only *false-negative* FULL (degrade to PARTIAL,
+costing a scan) and never *false-positive* NO_MATCH or FULL.  The join-key,
+enumeration and block-top-k planes keep the widening contract for every
+column.  ``tests/test_device_plane.py`` and ``tests/test_wide_columns.py``
+hold the regression tests.
 """
 
 from __future__ import annotations
@@ -159,6 +180,144 @@ def cast_bounds_f32(
     return lo32, hi32, exact
 
 
+# -- wide integer columns: exact limbs ---------------------------------------
+
+LIMB_BITS = 23            # a digit below 2**23, and a top digit of at most
+                          # 2**24 in magnitude, are exact in f32
+WIDE_MIN = 2.0 ** 24      # below: every integer casts to f32 exactly
+WIDE_MAX = 2.0 ** 53      # above: the host's f64 metadata is itself inexact
+# Per digit count: the |v - base| the staged stats must keep (a delta past
+# it restages), and the clamp of query bounds, strictly beyond every stat
+# so a clamped bound compares with each stat as the true bound does.
+_STAT_LIM = {2: 1 << 46, 3: 1 << 55}
+_BOUND_LIM = {2: 1 << 47, 3: 1 << 56}
+
+
+@dataclasses.dataclass(frozen=True)
+class WideColumn:
+    """Where a wide column's limb rows sit in the planes, most significant
+    first, and the offset they are taken from."""
+
+    cid: int
+    base: int
+    limbs: int
+    row: int
+
+
+def column_class(kind: str, mins: np.ndarray, maxs: np.ndarray) -> str:
+    """``"float"``, ``"narrow"`` (every value exact in f32), ``"wide"``
+    (integral, reaching 2**24, within 2**53) or ``"beyond"`` (an integer
+    column the f64 host stats cannot hold exactly either)."""
+    if kind == "float":
+        return "float"
+    v = np.concatenate([mins[np.isfinite(mins)], maxs[np.isfinite(maxs)]])
+    if not v.size or np.abs(v).max() < WIDE_MIN:
+        return "narrow"
+    if np.abs(v).max() <= WIDE_MAX and np.array_equal(v, np.floor(v)):
+        return "wide"
+    return "beyond"
+
+
+def stat_layout(stats: PartitionStats
+                ) -> Tuple[Tuple[WideColumn, ...], Tuple[int, ...]]:
+    """(wide, beyond): the table's wide columns with their limb rows
+    (after the C primary rows), and the ids of its integer columns beyond
+    2**53 — decided from the columns' own stats."""
+    wide, beyond = [], []
+    row = stats.num_columns
+    for c, meta in enumerate(stats.columns):
+        mn, mx = stats.mins[:, c], stats.maxs[:, c]
+        cls = column_class(meta.kind, mn, mx)
+        if cls == "beyond":
+            beyond.append(c)
+        if cls != "wide":
+            continue
+        fin = np.concatenate([mn[np.isfinite(mn)], mx[np.isfinite(mx)]])
+        base, top = int(fin.min()), int(fin.max())
+        limbs = 2 if top - base < _STAT_LIM[2] else 3
+        wide.append(WideColumn(c, base, limbs, row))
+        row += limbs
+    return tuple(wide), tuple(beyond)
+
+
+def _split(off: np.ndarray, limbs: int) -> np.ndarray:
+    """int64 offsets -> [limbs, n] f32 digits: a signed top digit (floor
+    division) and base-2**23 digits below it, all exact in f32."""
+    shift = LIMB_BITS * (limbs - 1)
+    top = off >> shift
+    rem = off - (top << shift)
+    out = np.empty((limbs, off.size), dtype=np.float32)
+    out[0] = top
+    for i in range(1, limbs):
+        out[i] = (rem >> (LIMB_BITS * (limbs - 1 - i))) & ((1 << LIMB_BITS) - 1)
+    return out
+
+
+def stat_limbs(values: np.ndarray, w: WideColumn) -> np.ndarray:
+    """A wide column's stat values -> [limbs, n] f32 limb rows.
+
+    Exact: the caller keeps every finite value integral and within
+    ``_STAT_LIM`` of ``w.base``.  Non-finite stats (empty and dropped
+    partitions) take ±f32max in every limb, the planes' drop sentinel,
+    so an empty interval stays empty under the lexicographic compare."""
+    v = np.asarray(values, dtype=np.float64)
+    fin = np.isfinite(v)
+    out = _split(np.where(fin, v, w.base).astype(np.int64) - w.base, w.limbs)
+    return np.where(fin, out, np.where(v > 0, _F32_MAX, -_F32_MAX)
+                    ).astype(np.float32)
+
+
+def bound_limbs(values: np.ndarray, w: WideColumn) -> np.ndarray:
+    """Snapped (integral) query bounds on a wide column -> [limbs, n] f32.
+
+    Bounds beyond every stat (infinite ones included) clamp to
+    ``_BOUND_LIM``, which keeps each comparison with a stat as it was, so
+    the split is exact."""
+    v = np.clip(np.asarray(values, dtype=np.float64), -2.0 ** 62, 2.0 ** 62)
+    lim = _BOUND_LIM[w.limbs]
+    off = np.clip(v.astype(np.int64) - w.base, -lim, lim)
+    return _split(off, w.limbs)
+
+
+def limb_rows(stats: PartitionStats, wide: Tuple[WideColumn, ...],
+              lo: int, hi: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Host (mins, maxs, demote) limb rows of partitions [lo, hi), one
+    block per wide column in ``wide`` order; demote is the null flag."""
+    ms, xs, ds = [], [], []
+    for w in wide:
+        ms.append(stat_limbs(stats.mins[lo:hi, w.cid], w))
+        xs.append(stat_limbs(stats.maxs[lo:hi, w.cid], w))
+        nul = (stats.null_counts[lo:hi, w.cid] > 0).astype(np.float32)
+        ds.append(np.broadcast_to(nul, (w.limbs, hi - lo)))
+    return (np.concatenate(ms), np.concatenate(xs), np.concatenate(ds))
+
+
+def limbs_fit(stats: PartitionStats, wide: Tuple[WideColumn, ...],
+              beyond: Tuple[int, ...], sel) -> bool:
+    """Whether the rows ``sel`` (a slice or ids) keep the staged layout:
+    every wide column stays integral and within its limbs' reach of its
+    base, and no narrow integer column reaches 2**24 (a fresh stage would
+    make it wide).  ``beyond`` columns stay as they are."""
+    wide_of = {w.cid: w for w in wide}
+    for c, meta in enumerate(stats.columns):
+        if meta.kind == "float" or c in beyond:
+            continue
+        v = np.concatenate([stats.mins[sel, c], stats.maxs[sel, c]])
+        v = v[np.isfinite(v)]
+        if not v.size:
+            continue
+        w = wide_of.get(c)
+        if w is None:
+            if np.abs(v).max() >= WIDE_MIN and np.abs(v).max() <= WIDE_MAX:
+                return False
+            continue
+        if np.abs(v).max() > WIDE_MAX or not np.array_equal(v, np.floor(v)):
+            return False
+        if np.abs(v - w.base).max() >= _STAT_LIM[w.limbs]:
+            return False
+    return True
+
+
 def snap_bounds_integral(
     los: np.ndarray, his: np.ndarray, integral: np.ndarray
 ) -> Tuple[np.ndarray, np.ndarray]:
@@ -236,6 +395,11 @@ class DeviceStats:
     # separate reads a concurrent replay could tear across.
     planes_state: Tuple
     integral: np.ndarray   # [C] bool, host-side: int/dictionary-code column
+    # the staged layout, fixed for this entry's life (a delta that would
+    # change it restages a new entry): wide columns' limb rows, and the
+    # integer columns beyond 2**53 that keep the widening contract
+    wide: Tuple[WideColumn, ...] = ()
+    beyond: Tuple[int, ...] = ()
     live_count: int = -1
     tv_version: Optional[int] = None   # service TableVersion seen at staging
     # integrity stamp over the planes' bytes, computed host-side at stage
@@ -272,7 +436,9 @@ class DeviceStats:
 
     @property
     def num_columns(self) -> int:
-        return self.mins.shape[0]
+        """Primary rows: one per column (the wide columns' limb rows
+        follow them)."""
+        return len(self.integral)
 
     @property
     def num_partitions(self) -> int:
@@ -287,8 +453,51 @@ class DeviceStats:
         return PlaneEpoch(self.version, self.live_count, self.capacity)
 
     @property
+    def primary_planes(self) -> Tuple:
+        """The planes' primary rows: what the tree hulls aggregate (a
+        hull of limbs is no hull of values)."""
+        C = self.num_columns
+        if int(self.mins.shape[0]) == C:
+            return self.planes
+        return tuple(a[:C] for a in self.planes)
+
+    @property
     def nbytes(self) -> int:
         return int(sum(int(a.nbytes) for a in self.planes))
+
+    def limb_slots(self, cids: np.ndarray, lo64: np.ndarray,
+                   hi64: np.ndarray, lo32: np.ndarray, hi32: np.ndarray,
+                   valid: np.ndarray):
+        """The kernels' constraint slots for packed ``[Qb, Kb]`` ranges.
+
+        Returns ``(rows, lo, hi, wide, limbs)``: ``limbs`` slots per
+        constraint (1 when no valid constraint is on a wide column, and
+        then the slots are ``cids, lo32, hi32`` themselves), the slot
+        tables ``[Qb, Kb * limbs]``, and ``wide`` ``[Qb, Kb]`` marking the
+        constraints compared on limbs — exact, so never FULL-demoted.  A
+        wide constraint's slots are its limb rows and ``bound_limbs`` of
+        its snapped f64 bounds; every other constraint keeps its primary
+        row and widened bounds in the first slot, row -1 and bounds 0 in
+        the others (its compare is the plain one)."""
+        wide_of = {w.cid: w for w in self.wide}
+        wide = valid & np.isin(cids, list(wide_of))
+        if not wide.any():
+            return cids, lo32, hi32, wide, 1
+        used = [wide_of[c] for c in np.unique(cids[wide]).tolist()]
+        limbs = max(w.limbs for w in used)
+        Qb, Kb = cids.shape
+        rows = np.full((Qb, Kb, limbs), -1, dtype=np.int32)
+        lo = np.zeros((Qb, Kb, limbs), dtype=np.float32)
+        hi = np.zeros((Qb, Kb, limbs), dtype=np.float32)
+        rows[..., 0], lo[..., 0], hi[..., 0] = cids, lo32, hi32
+        for w in used:
+            m = wide & (cids == w.cid)
+            rows[m, :w.limbs] = w.row + np.arange(w.limbs)
+            lo[m, :w.limbs] = bound_limbs(lo64[m], w).T
+            hi[m, :w.limbs] = bound_limbs(hi64[m], w).T
+        flat = (Qb, Kb * limbs)
+        return (rows.reshape(flat), lo.reshape(flat), hi.reshape(flat),
+                wide, limbs)
 
     def gather(self, cids: np.ndarray):
         """On-device row gather -> per-constraint [K, cap] planes.
@@ -316,8 +525,16 @@ class DeviceStats:
         cap = P if capacity is None else max(int(capacity), P)
         mins32, maxs32, inexact = cast_stats_f32(stats.mins.T, stats.maxs.T)
         demote = ((stats.null_counts.T > 0) | inexact).astype(np.float32)
+        wide, beyond = stat_layout(stats)
+        if wide:
+            with span("planes.limbs", cols=len(wide),
+                      bytes=3 * 4 * P * sum(w.limbs for w in wide)):
+                lm, lx, ld = limb_rows(stats, wide, 0, P)
+            mins32 = np.concatenate([mins32, lm])
+            maxs32 = np.concatenate([maxs32, lx])
+            demote = np.concatenate([demote, ld])
         if cap > P:
-            C = len(stats.columns)
+            C = mins32.shape[0]
             pad = cap - P
             mins32 = np.concatenate(
                 [mins32, np.full((C, pad), _F32_MAX, np.float32)], axis=1)
@@ -334,6 +551,8 @@ class DeviceStats:
             planes_state=((jnp.asarray(mins32), jnp.asarray(maxs32),
                            jnp.asarray(demote)), P),
             integral=integral,
+            wide=wide,
+            beyond=beyond,
             live_count=live_count,
             # stamped from the host arrays pre-H2D: free at stage time
             checksum=plane_checksum((mins32, maxs32, demote)),
@@ -433,7 +652,7 @@ def tree_entry_for(dstats: "DeviceStats", fanout: int = TREE_FANOUT,
     group + coarse arrays in ``arrays``, geometry in ``meta``.  The
     cache's build path delegates here so the two can never drift.
     """
-    arrays = aggregate_tree_planes(*dstats.planes, fanout=fanout)
+    arrays = aggregate_tree_planes(*dstats.primary_planes, fanout=fanout)
     return _PlaneEntry(
         version,
         dstats.num_partitions if logical_p is None else int(logical_p),
@@ -976,11 +1195,18 @@ class DeviceStatsCache:
     # ---- [C, cap] stat planes ------------------------------------------
 
     @staticmethod
-    def _stat_cols(stats: PartitionStats, lo: int, hi: int):
-        """Host f32 plane columns for partitions [lo, hi) (delta slice)."""
+    def _stat_cols(stats: PartitionStats, wide, lo: int, hi: int):
+        """Host f32 plane columns for partitions [lo, hi) (delta slice),
+        the wide columns' limb rows included."""
         m32, x32, inexact = cast_stats_f32(stats.mins[lo:hi].T,
                                            stats.maxs[lo:hi].T)
         dm = ((stats.null_counts[lo:hi].T > 0) | inexact).astype(np.float32)
+        if wide:
+            with span("planes.limbs", cols=len(wide),
+                      bytes=3 * 4 * (hi - lo) * sum(w.limbs for w in wide)):
+                lm, lx, ld = limb_rows(stats, wide, lo, hi)
+            m32, x32, dm = (np.concatenate(p) for p in
+                            ((m32, lm), (x32, lx), (dm, ld)))
         return m32, x32, dm
 
     def _replay_stats(self, e: DeviceStats, table, deltas) -> bool:
@@ -995,11 +1221,15 @@ class DeviceStatsCache:
             if stats.num_partitions > e.capacity:
                 return False
             mins, maxs, dem = e.planes
+            rows = int(mins.shape[0])
             nbytes = 0
             for d in deltas:
                 if d.kind == "append":
-                    m32, x32, dm = self._stat_cols(stats, d.part_lo, d.part_hi)
                     sl = slice(d.part_lo, d.part_hi)
+                    if not limbs_fit(stats, e.wide, e.beyond, sl):
+                        return False       # the layout changes: restage
+                    m32, x32, dm = self._stat_cols(stats, e.wide, d.part_lo,
+                                                   d.part_hi)
                     mins = mins.at[:, sl].set(jnp.asarray(m32))
                     maxs = maxs.at[:, sl].set(jnp.asarray(x32))
                     dem = dem.at[:, sl].set(jnp.asarray(dm))
@@ -1009,13 +1239,16 @@ class DeviceStatsCache:
                     mins = mins.at[:, ids].set(_F32_MAX)
                     maxs = maxs.at[:, ids].set(-_F32_MAX)
                     dem = dem.at[:, ids].set(np.float32(1.0))
-                    nbytes += 3 * e.num_columns * len(d.part_ids) * 4
+                    nbytes += 3 * rows * len(d.part_ids) * 4
                 elif d.kind == "update":
                     try:
                         ci = stats.col_id(d.column)
                     except KeyError:
                         return False
                     P = stats.num_partitions
+                    if not limbs_fit(stats, e.wide, e.beyond, slice(0, P)):
+                        return False
+                    wide = tuple(w for w in e.wide if w.cid == ci)
                     m32, x32, inexact = cast_stats_f32(
                         stats.mins[:, ci][None, :], stats.maxs[:, ci][None, :])
                     dm = ((stats.null_counts[:, ci][None, :] > 0)
@@ -1024,6 +1257,16 @@ class DeviceStatsCache:
                     maxs = maxs.at[ci, :P].set(jnp.asarray(x32[0]))
                     dem = dem.at[ci, :P].set(jnp.asarray(dm[0]))
                     nbytes += 3 * P * 4
+                    if wide:
+                        w = wide[0]
+                        with span("planes.limbs", cols=1,
+                                  bytes=3 * 4 * P * w.limbs):
+                            lm, lx, ld = limb_rows(stats, wide, 0, P)
+                        rs = slice(w.row, w.row + w.limbs)
+                        mins = mins.at[rs, :P].set(jnp.asarray(lm))
+                        maxs = maxs.at[rs, :P].set(jnp.asarray(lx))
+                        dem = dem.at[rs, :P].set(jnp.asarray(ld))
+                        nbytes += int(lm.nbytes + lx.nbytes + ld.nbytes)
                 else:                      # rewrite (or unknown): full restage
                     return False
             # re-stamp from the clean replayed arrays, then let the chaos
@@ -1474,7 +1717,7 @@ class DeviceStatsCache:
         fanout = e.meta["fanout"]
         gm, gx, gd = e.arrays[:3]
         C, G = int(gm.shape[0]), int(gm.shape[1])
-        mins, maxs, dem = dstats.planes
+        mins, maxs, dem = dstats.primary_planes
         dirty: set = set()
         rows: set = set()
         for d in deltas:

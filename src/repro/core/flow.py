@@ -267,12 +267,13 @@ class LimitTechnique(Technique):
         if not (pipe.enable_limit and q.is_plain_limit):
             return
         for name, spec in q.scans.items():
-            res = limit_prune(
-                state.scan_sets[name],
-                spec.table.stats,
-                q.effective_k,
-                supported_shape=pipe._limit_supported(q, name),
-            )
+            with span("limit.select", P=len(state.scan_sets[name])):
+                res = limit_prune(
+                    state.scan_sets[name],
+                    spec.table.stats,
+                    q.effective_k,
+                    supported_shape=pipe._limit_supported(q, name),
+                )
             state.scan_sets[name] = res.scan
             state.per_scan[name]["limit"] = TechniqueReport(
                 res.partitions_before, res.partitions_after,

@@ -22,8 +22,17 @@ Per (query, constraint, partition) the three-valued lattice is the same
 as minmax_prune.py; no-op padding rows contribute tv=2 (the AND identity).
 A padded query row (all no-ops) therefore yields tv=2 and is sliced off.
 
+Wide integer columns (``limbs > 1``; core/device_stats.py precision
+contract): each constraint brings ``limbs`` (row, lo, hi) slots, most
+significant first, and compares its stats with its bounds
+lexicographically over the limbs, every one exact in f32.  A narrow
+constraint in such a launch fills its first slot as with ``limbs=1`` and
+its others with row -1 (the one-hot gather reads 0) and bounds 0, so its
+compare is the plain one.  The first slot carries the no-op padding test
+and the demote row.
+
 Block layout per grid step (i over query blocks, j over partition blocks):
-  cids/lo/hi:        [BLOCK_Q, Kb]  (i, 0)
+  cids/lo/hi:        [BLOCK_Q, Kb * limbs]  (i, 0)
   mins/maxs/demote:  [C, BLOCK_P]   (0, j)   — revisited, stays in VMEM
   tv out:            [BLOCK_Q, BLOCK_P] int32 (i, j)
 """
@@ -44,9 +53,9 @@ _POS = float("inf")
 
 
 def _batched_kernel(cids_ref, lo_ref, hi_ref, mins_ref, maxs_ref, dem_ref,
-                    tv_ref):
+                    tv_ref, *, limbs: int):
     C = mins_ref.shape[0]
-    BQ, Kb = lo_ref.shape
+    BQ, KL = lo_ref.shape
     BP = mins_ref.shape[1]
     mins = mins_ref[...]          # [C, BP]
     maxs = maxs_ref[...]
@@ -54,25 +63,40 @@ def _batched_kernel(cids_ref, lo_ref, hi_ref, mins_ref, maxs_ref, dem_ref,
     col_iota = jax.lax.broadcasted_iota(jnp.int32, (BQ, C), 1)
 
     tv = jnp.full((BQ, BP), 2, dtype=jnp.int32)
-    for k in range(Kb):           # static unroll: Kb is a small power of two
-        cid = cids_ref[:, k]                       # [BQ] int32
-        onehot = (cid[:, None] == col_iota).astype(jnp.float32)
-        # One-hot gather: exactly one 1.0 per row, so the matmul is an
-        # exact row select, executed on the MXU — at HIGHEST precision
-        # only: the TPU's default single bf16 pass keeps 8 mantissa bits
-        # and rounds the stats (measured on a v5e).  The 0/1 demote flags
-        # are exact in one pass.
-        pmin = jnp.dot(onehot, mins, precision=jax.lax.Precision.HIGHEST,
-                       preferred_element_type=jnp.float32)
-        pmax = jnp.dot(onehot, maxs, precision=jax.lax.Precision.HIGHEST,
-                       preferred_element_type=jnp.float32)
+    for k in range(KL // limbs):  # static unroll: Kb is a small power of two
+        # Lexicographic compare, least significant limb first: each
+        # relation holds where it holds at this limb, or where the limb
+        # ties and it held below.  With limbs=1 it is the plain compare.
+        lt_xa = gt_mb = lt_ma = gt_xb = gt_mx = None
+        for j in reversed(range(k * limbs, (k + 1) * limbs)):
+            cid = cids_ref[:, j]                   # [BQ] int32
+            onehot = (cid[:, None] == col_iota).astype(jnp.float32)
+            # One-hot gather: exactly one 1.0 per row, so the matmul is an
+            # exact row select, executed on the MXU — at HIGHEST precision
+            # only: the TPU's default single bf16 pass keeps 8 mantissa
+            # bits and rounds the stats (measured on a v5e).
+            pmin = jnp.dot(onehot, mins, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+            pmax = jnp.dot(onehot, maxs, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+            lo = lo_ref[:, j][:, None]             # [BQ, 1]
+            hi = hi_ref[:, j][:, None]
+            if lt_xa is None:
+                lt_xa, gt_mb = pmax < lo, pmin > hi
+                lt_ma, gt_xb, gt_mx = pmin < lo, pmax > hi, pmin > pmax
+            else:
+                lt_xa = (pmax < lo) | ((pmax == lo) & lt_xa)
+                gt_mb = (pmin > hi) | ((pmin == hi) & gt_mb)
+                lt_ma = (pmin < lo) | ((pmin == lo) & lt_ma)
+                gt_xb = (pmax > hi) | ((pmax == hi) & gt_xb)
+                gt_mx = (pmin > pmax) | ((pmin == pmax) & gt_mx)
+        # the first slot's row and bounds are left in onehot / lo / hi;
+        # the 0/1 demote flags are exact in one pass
         pdem = jnp.dot(onehot, dem, preferred_element_type=jnp.float32)
-        lo = lo_ref[:, k][:, None]                 # [BQ, 1]
-        hi = hi_ref[:, k][:, None]
 
-        empty = pmin > pmax
-        no = (pmax < lo) | (pmin > hi) | empty
-        full = (pmin >= lo) & (pmax <= hi) & (pdem == 0.0) & ~empty
+        empty = gt_mx
+        no = lt_xa | gt_mb | empty
+        full = ~lt_ma & ~gt_xb & (pdem == 0.0) & ~empty
         tv_k = jnp.where(no, 0, jnp.where(full, 2, 1)).astype(jnp.int32)
         # (-inf, +inf) is the padding sentinel: the AND identity regardless
         # of the gathered stats (extract_ranges never emits it for a real
@@ -83,15 +107,16 @@ def _batched_kernel(cids_ref, lo_ref, hi_ref, mins_ref, maxs_ref, dem_ref,
     tv_ref[...] = tv
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "limbs"))
 def minmax_prune_batched(
-    cids: jax.Array,      # [Q, Kb] int32 constraint column ids
-    lo: jax.Array,        # [Q, Kb] f32 range lows  (inclusive; -inf pad)
-    hi: jax.Array,        # [Q, Kb] f32 range highs (inclusive; +inf pad)
+    cids: jax.Array,      # [Q, Kb * limbs] int32 plane rows (-1: reads 0)
+    lo: jax.Array,        # [Q, Kb * limbs] f32 range lows  (inclusive; -inf pad)
+    hi: jax.Array,        # [Q, Kb * limbs] f32 range highs (inclusive; +inf pad)
     mins: jax.Array,      # [C, P] f32 resident partition minima (widened)
     maxs: jax.Array,      # [C, P] f32 resident partition maxima (widened)
     demote: jax.Array,    # [C, P] f32 1.0 where FULL must be suppressed
     interpret: bool = False,
+    limbs: int = 1,       # slots per constraint (wide columns: 2 or 3)
 ) -> jax.Array:
     """Returns tv [Q, P] int32 in {0, 1, 2}.
 
@@ -122,7 +147,7 @@ def minmax_prune_batched(
 
     grid = (Qp // BLOCK_Q, Pp // BLOCK_P)
     tv = pl.pallas_call(
-        _batched_kernel,
+        functools.partial(_batched_kernel, limbs=limbs),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_Q, Kb), lambda i, j: (i, 0)),

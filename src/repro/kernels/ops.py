@@ -25,7 +25,10 @@ Two staging regimes coexist:
 All f32 downcasts go through ``core.device_stats`` (widening + demotion;
 see its precision contract).  Integral columns (int / dictionary codes)
 get their query bounds snapped to integers first, so the f32 path stays
-exactly equal to the f64 host oracle on the paper's workloads.
+exactly equal to the f64 host oracle on the paper's workloads; on wide
+integer columns (values from 2**24 up to 2**53, e.g. microsecond
+timestamps) the batched filter compares exact limbs instead of the
+widened values (``pack_ranges``).
 """
 
 from __future__ import annotations
@@ -151,11 +154,12 @@ def _note_shards(n: int) -> int:
 # launches over a handful of shape buckets.
 
 @functools.lru_cache(maxsize=None)
-def _sharded_minmax(mesh, use_kernel: bool, interp: bool):
+def _sharded_minmax(mesh, use_kernel: bool, interp: bool, limbs: int = 1):
     def body(c, l, h, m, x, d):
         if use_kernel:
-            return minmax_prune_batched(c, l, h, m, x, d, interpret=interp)
-        return ref.minmax_prune_batched_ref(c, l, h, m, x, d)
+            return minmax_prune_batched(c, l, h, m, x, d, interpret=interp,
+                                        limbs=limbs)
+        return ref.minmax_prune_batched_ref(c, l, h, m, x, d, limbs=limbs)
 
     rep, sp = PSpec(), PSpec(None, PLANE_AXIS)
     return jax.jit(jax.shard_map(body, mesh=mesh,
@@ -328,15 +332,24 @@ def prune_ranges_device(
 def pack_ranges(
     range_lists: Sequence[List[Tuple[int, float, float]]],
     dstats: DeviceStats,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    min_k: int = 1,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, Tuple]:
     """Pack per-query constraint lists into [Qb, Kb] kernel inputs.
 
-    Returns (cids int32, lo f32, hi f32, full_safe bool[Q]).  Constraint
-    slots beyond a query's K and query rows beyond Q are ``(-inf, +inf)``
-    no-ops; Kb/Qb are power-of-two buckets so recompiles stay bounded.
+    Returns (cids int32, lo f32, hi f32, full_safe bool[Q], slots).  The
+    first three are the primary rows and widened bounds, which the tree
+    path's hull tests read; ``slots`` = (rows, lo, hi, limbs) are what the
+    filter kernels evaluate: the same tables when no constraint is on a
+    wide column, else ``limbs`` exact slots per constraint
+    (``DeviceStats.limb_slots``).  Constraint slots beyond a query's K and
+    query rows beyond Q are ``(-inf, +inf)`` no-ops; Kb (at least
+    ``min_k``) and Qb are power-of-two buckets so recompiles stay bounded.
+    ``last_pack_stats`` then counts
+    the batch's constraints compared on limbs (``wide``) and those whose
+    bounds f32 could not hold (``demoted``: FULL demoted for the query).
     """
     Q = len(range_lists)
-    Kb = k_bucket(max((len(r) for r in range_lists), default=1))
+    Kb = k_bucket(max([min_k] + [len(r) for r in range_lists]))
     Qb = q_bucket(Q)
     cids = np.zeros((Qb, Kb), dtype=np.int32)
     valid = np.zeros((Qb, Kb), dtype=bool)
@@ -354,11 +367,27 @@ def pack_ranges(
     # sentinel on padding slots so the kernel's no-op detection fires.
     lo32 = np.where(valid, lo32, np.float32(-np.inf))
     hi32 = np.where(valid, hi32, np.float32(np.inf))
-    full_safe = (exact | ~valid).all(axis=1)[:Q]
-    return cids, lo32, hi32, full_safe
+    rows, slo, shi, wide, limbs = dstats.limb_slots(cids, lo64, hi64, lo32,
+                                                    hi32, valid)
+    full_safe = (exact | ~valid | wide).all(axis=1)[:Q]
+    _pack_note.d = dict(wide=int(wide.sum()),
+                        demoted=int((valid & ~exact & ~wide).sum()))
+    return cids, lo32, hi32, full_safe, (rows, slo, shi, limbs)
 
 
-_batched_ref_jit = jax.jit(ref.minmax_prune_batched_ref)
+# What the most recent pack on THIS thread found (constraints compared on
+# limbs, constraints whose bounds f32 could not hold) — the service's
+# filter counters read it after each launch; thread-local like the notes
+# below.
+_pack_note = threading.local()
+
+
+def last_pack_stats() -> dict:
+    return getattr(_pack_note, "d", {})
+
+
+_batched_ref_jit = jax.jit(ref.minmax_prune_batched_ref,
+                           static_argnames=("limbs",))
 
 
 def _fetch(out) -> np.ndarray:
@@ -383,10 +412,11 @@ def prune_ranges_batched_device(
 ) -> np.ndarray:
     """Evaluate Q queries' conjunctive ranges in one batched launch.
 
-    Returns tv ``[Q, P]`` int8 — row q is identical to the per-query
-    device path for query q's ranges, and to the f64 host oracle on
-    int/dictionary workloads (bounds snap to integers and cast exactly).
-    Bounds that are inexact in f32 demote FULL to PARTIAL — never a false
+    Returns tv ``[Q, P]`` int8 — row q is identical to the f64 host
+    oracle on int/dictionary columns up to 2**53 (bounds snap to
+    integers; below 2**24 they cast exactly, above it a wide column's
+    limbs compare exactly).  Bounds that are inexact in f32 (floats,
+    integers beyond 2**53) demote FULL to PARTIAL — never a false
     NO_MATCH or false FULL (core.device_stats precision contract).
 
     With ``mesh`` (``launch.mesh.make_plane_mesh``) the resident planes
@@ -403,9 +433,10 @@ def prune_ranges_batched_device(
         planes, P = dstats.planes_state
         mins, maxs, demote = planes
         Pc = int(mins.shape[1])        # staged capacity (>= P; sentinel tail)
-        cids, lo, hi, full_safe = pack_ranges(range_lists, dstats)
-        Qb = cids.shape[0]
-        cids_d = jnp.asarray(cids)
+        _c, _l, _h, full_safe, (rows, lo, hi, limbs) = pack_ranges(
+            range_lists, dstats)
+        Qb = rows.shape[0]
+        cids_d = jnp.asarray(rows)
         lo_d = jnp.asarray(lo)
         hi_d = jnp.asarray(hi)
     shards = mesh_shards(mesh, Pc)
@@ -416,22 +447,25 @@ def prune_ranges_batched_device(
     _note_shards(shards)
     with span("dispatch", q=Q, P=P):
         if shards > 1:
-            fn = _sharded_minmax(mesh, *_body_flags(mode))
+            fn = _sharded_minmax(mesh, *_body_flags(mode), limbs)
             out = fn(cids_d, lo_d, hi_d, mins, maxs, demote)
         elif mode == "ref" or (mode == "auto" and not _on_tpu()):
             slab = max(1024, _REF_SLAB_ELEMS // Qb)
             if slab >= Pc:
-                out = _batched_ref_jit(cids_d, lo_d, hi_d, mins, maxs, demote)
+                out = _batched_ref_jit(cids_d, lo_d, hi_d, mins, maxs, demote,
+                                       limbs=limbs)
             else:
                 out = [_batched_ref_jit(
                     cids_d, lo_d, hi_d,
                     jax.lax.slice_in_dim(mins, s, min(s + slab, Pc), axis=1),
                     jax.lax.slice_in_dim(maxs, s, min(s + slab, Pc), axis=1),
-                    jax.lax.slice_in_dim(demote, s, min(s + slab, Pc), axis=1))
+                    jax.lax.slice_in_dim(demote, s, min(s + slab, Pc), axis=1),
+                    limbs=limbs)
                     for s in range(0, Pc, slab)]
         else:
             out = minmax_prune_batched(cids_d, lo_d, hi_d, mins, maxs, demote,
-                                       interpret=kernel_interpret(mode))
+                                       interpret=kernel_interpret(mode),
+                                       limbs=limbs)
     tv = _fetch(out)
     with span("unpack", q=Q, P=P):
         tv = tv[:Q, :P].astype(np.int8)
@@ -515,7 +549,18 @@ def _note_tree(**kw) -> None:
     _tree_note.d = dict(kw)
 
 
-_gathered_ref_jit = jax.jit(ref.minmax_prune_gathered_ref)
+_gathered_ref_jit = jax.jit(ref.minmax_prune_gathered_ref,
+                            static_argnames=("limbs",))
+
+# Positions per gathered launch of the tree path (a multiple of every
+# pow-2 fanout up to it).  Each level launches fixed-width chunks of its
+# survivor positions, so a table geometry compiles one shape per level
+# however many groups survive: survivor counts vary batch by batch, and a
+# width that followed them would compile inside a serving window.  The
+# constraint dim has a floor for the same reason: a micro-batch whose
+# queries all carry one range would otherwise compile a Kb of its own.
+TREE_CHUNK = 1 << 14
+TREE_MIN_K = 4
 
 
 def _coarse_survivors(cids, lo, hi, cmins, cmaxs) -> np.ndarray:
@@ -535,18 +580,20 @@ def _coarse_survivors(cids, lo, hi, cmins, cmaxs) -> np.ndarray:
     return surv
 
 
-def _survivor_positions(surv: np.ndarray, span: int) -> np.ndarray:
-    """pos [Q, Sb * span] int32 — each row's surviving ids expanded to
+def _survivor_positions(surv: np.ndarray, span: int,
+                        width: int) -> np.ndarray:
+    """pos [Q, n * width] int32 — each row's surviving ids expanded to
     their ``span`` child positions (id * span + j), right-padded with id
-    0's children up to the pow-2 bucket Sb of the max per-row survivor
-    count (bounded jit shapes).  Padding is *exact*, not a sentinel: the
-    gathered evaluator computes the true verdict at every listed
-    position, and scattering a truthful verdict twice — or for a
-    non-surviving group, whose members are provably NO — changes
-    nothing."""
+    0's children to the fewest whole chunks of ``width`` columns (a
+    multiple of ``span``) that hold the max per-row count.  Padding is
+    *exact*, not a sentinel: the gathered evaluator computes the true
+    verdict at every listed position, and scattering a truthful verdict
+    twice — or for a non-surviving group, whose members are provably NO
+    — changes nothing."""
     Q = surv.shape[0]
     counts = surv.sum(axis=1)
-    sb = _pow2_at_least(max(int(counts.max()), 1))
+    per = width // span
+    sb = per * max(1, -(-int(counts.max()) // per))
     ids = np.zeros((Q, sb), dtype=np.int64)
     qs, gs = np.nonzero(surv)
     col = np.arange(len(qs)) - np.repeat(np.cumsum(counts) - counts, counts)
@@ -554,6 +601,21 @@ def _survivor_positions(surv: np.ndarray, span: int) -> np.ndarray:
     pos = (ids[:, :, None] * span
            + np.arange(span, dtype=np.int64)[None, None, :])
     return pos.reshape(Q, sb * span).astype(np.int32)
+
+
+def _gathered(tables, planes, pos: np.ndarray, width: int, Qb: int,
+              limbs: int) -> list:
+    """The gathered evaluator over ``pos`` in launches of ``width``
+    columns, query rows padded to ``Qb``; chunks are cut on the host, so
+    no slice compiles either."""
+    Q = pos.shape[0]
+    out = []
+    for s in range(0, pos.shape[1], width):
+        chunk = np.zeros((Qb, width), dtype=np.int32)
+        chunk[:Q] = pos[:, s:s + width]
+        out.append(_gathered_ref_jit(*tables, *planes, jnp.asarray(chunk),
+                                     limbs=limbs))
+    return out
 
 
 def prune_ranges_batched_tree(
@@ -568,14 +630,17 @@ def prune_ranges_batched_tree(
 
     Bit-identical to ``prune_ranges_batched_device`` row for row (and so
     to the f64 host oracle wherever the flat path is): the pre-pass only
-    removes positions whose group hull *proves* NO_MATCH.  Falls back to
-    the flat launch when the table is too small for the tree geometry or
-    the coarse survivor density exceeds ``dense_cutoff`` — the density
-    check runs on the host coarse level, so the dense-workload fallback
-    never pays a pre-pass launch.  The gathered evaluations use the jnp
-    oracle on every backend (XLA-native gathers; the Pallas kernel
-    remains the flat path's dense evaluator), and are unsharded — a mesh
-    is forwarded to the flat fallback only.
+    removes positions whose group hull *proves* NO_MATCH.  The hulls and
+    the coarse root aggregate the primary rows, widened f32, so the
+    pre-pass reads the widened bounds; the leaves evaluate the kernel's
+    exact slots (wide columns' limbs).  Falls back to the flat launch when
+    the table is too small for the tree geometry or the coarse survivor
+    density exceeds ``dense_cutoff`` — the density check runs on the host
+    coarse level, so the dense-workload fallback never pays a pre-pass
+    launch.  The gathered evaluations use the jnp oracle on every backend
+    (XLA-native gathers; the Pallas kernel remains the flat path's dense
+    evaluator) in fixed-width chunks (``TREE_CHUNK``), and are unsharded
+    — a mesh is forwarded to the flat fallback only.
     """
     Q = len(range_lists)
     planes, P = dstats.planes_state
@@ -589,7 +654,8 @@ def prune_ranges_batched_tree(
         _note_tree(path="flat_small", groups=G)
         return prune_ranges_batched_device(range_lists, dstats, mode,
                                            mesh=mesh)
-    cids, lo, hi, full_safe = pack_ranges(range_lists, dstats)
+    cids, lo, hi, full_safe, (rows, slo, shi, limbs) = pack_ranges(
+        range_lists, dstats, min_k=TREE_MIN_K)
     Qb = cids.shape[0]
     # Level 0 — padding rows beyond Q are all-no-op and survive
     # everything; the density must price only the real rows.
@@ -605,19 +671,15 @@ def prune_ranges_batched_tree(
         _note_tree(path="flat_dense", groups=G, coarse_density=float(cdens))
         return prune_ranges_batched_device(range_lists, dstats, mode,
                                            mesh=mesh)
-    def pad_rows(a):
-        return np.concatenate(
-            [a, np.zeros((Qb - Q, a.shape[1]), dtype=a.dtype)], axis=0)
-
-    # Level 1 — fine group pre-pass over coarse-survivor children only.
+    # Level 1 — fine group pre-pass over coarse-survivor children only,
+    # on the widened primary tables.
+    f2 = G // G2
+    width1 = max(min(G, TREE_CHUNK), f2)
     with span("pack", q=Q):
-        cids_d = jnp.asarray(cids)
-        lo_d = jnp.asarray(lo)
-        hi_d = jnp.asarray(hi)
-        gpos = _survivor_positions(csurv, G // G2)        # [Q, S2b * f2]
-        gpos_d = jnp.asarray(pad_rows(gpos))
+        prim = (jnp.asarray(cids), jnp.asarray(lo), jnp.asarray(hi))
+        gpos = _survivor_positions(csurv, f2, width1)
     with span("dispatch", q=Q, P=G):
-        out = _gathered_ref_jit(cids_d, lo_d, hi_d, gm, gx, gd, gpos_d)
+        out = _gathered(prim, (gm, gx, gd), gpos, width1, Qb, 1)
     tvg = _fetch(out)
     with span("unpack", q=Q, P=G):
         tvg = tvg[:Q]
@@ -625,24 +687,19 @@ def prune_ranges_batched_tree(
         qrow = np.repeat(np.arange(Q), gpos.shape[1])
         gsurv[qrow, gpos.reshape(-1)] = (tvg > 0).reshape(-1)
         fdens = gsurv.sum(axis=1).max() / G
-    # Level 2 — gathered leaf evaluation over surviving groups' members,
-    # slabbed like the flat ref path (slab and W are both pow-2 multiples
-    # of fanout, so chunk widths repeat and recompiles stay bounded).
+    # Level 2 — gathered leaf evaluation of the kernel's slots over
+    # surviving groups' members, in chunks no wider than the flat ref
+    # path's slab (slab and chunk are pow-2 multiples of fanout).
     with span("pack", q=Q):
-        pos = _survivor_positions(gsurv, fanout)          # [Q, Sb * fanout]
-        W = pos.shape[1]
         groups_per_slab = max(1, (_REF_SLAB_ELEMS // max(Qb, 1)) // fanout)
         slab = fanout * (1 << (groups_per_slab.bit_length() - 1))
-        pos_d = jnp.asarray(pad_rows(pos))
+        width2 = max(fanout, min(Pc, TREE_CHUNK, slab))
+        pos = _survivor_positions(gsurv, fanout, width2)   # [Q, n * width2]
+        W = pos.shape[1]
+        slots = prim if limbs == 1 else (jnp.asarray(rows), jnp.asarray(slo),
+                                         jnp.asarray(shi))
     with span("dispatch", q=Q, P=P):
-        if W <= slab:
-            out = _gathered_ref_jit(cids_d, lo_d, hi_d, mins, maxs, demote,
-                                    pos_d)
-        else:
-            out = [_gathered_ref_jit(
-                cids_d, lo_d, hi_d, mins, maxs, demote,
-                jax.lax.slice_in_dim(pos_d, s, min(s + slab, W), axis=1))
-                for s in range(0, W, slab)]
+        out = _gathered(slots, (mins, maxs, demote), pos, width2, Qb, limbs)
     tvl = _fetch(out)
     _note_shards(1)
     with span("unpack", q=Q, P=P):

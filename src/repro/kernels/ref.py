@@ -22,35 +22,73 @@ def minmax_prune_ref(lo, hi, mins, maxs, nullable) -> jax.Array:
     return jnp.min(tv_k, axis=0)
 
 
-def minmax_prune_batched_ref(cids, lo, hi, mins, maxs, demote) -> jax.Array:
+def _relations(rows, lo, hi):
+    """One constraint's five stat-vs-bound relations (pmax < lo,
+    pmin > hi, pmin < lo, pmax > hi, pmin > pmax) from its gathered
+    (pmin, pmax) limb pairs, most significant first, and its [Q, 1] limb
+    bounds: the lexicographic compare of kernels/minmax_prune_batched.py,
+    least significant limb first (the plain compare with one limb)."""
+    lt_xa = gt_mb = lt_ma = gt_xb = gt_mx = None
+    for i in reversed(range(len(rows))):
+        pmin, pmax = rows[i]
+        a, b = lo[i], hi[i]
+        if lt_xa is None:
+            lt_xa, gt_mb = pmax < a, pmin > b
+            lt_ma, gt_xb, gt_mx = pmin < a, pmax > b, pmin > pmax
+        else:
+            lt_xa = (pmax < a) | ((pmax == a) & lt_xa)
+            gt_mb = (pmin > b) | ((pmin == b) & gt_mb)
+            lt_ma = (pmin < a) | ((pmin == a) & lt_ma)
+            gt_xb = (pmax > b) | ((pmax == b) & gt_xb)
+            gt_mx = (pmin > pmax) | ((pmin == pmax) & gt_mx)
+    return lt_xa, gt_mb, lt_ma, gt_xb, gt_mx
+
+
+def _combine(tv, rel, pdem, lo0, hi0):
+    """AND one constraint's tri-valued verdict into ``tv``; the first
+    limb's bounds carry the ``(-inf, +inf)`` padding no-op."""
+    lt_xa, gt_mb, lt_ma, gt_xb, empty = rel
+    no = lt_xa | gt_mb | empty
+    full = ~lt_ma & ~gt_xb & (pdem == 0.0) & ~empty
+    tv_k = jnp.where(no, 0, jnp.where(full, 2, 1)).astype(jnp.int32)
+    noop = (lo0 == -jnp.inf) & (hi0 == jnp.inf)
+    return jnp.minimum(tv, jnp.where(noop, 2, tv_k))
+
+
+def minmax_prune_batched_ref(cids, lo, hi, mins, maxs, demote,
+                             limbs: int = 1) -> jax.Array:
     """tv [Q, P] int32 for Q queries of Kb ranges over resident [C, P] stats.
 
     Mirrors kernels/minmax_prune_batched.py: per-constraint stat rows are
-    gathered from the resident planes by column id; ``(-inf, +inf)``
-    constraints are padding no-ops (tv=2, the AND identity).  The K loop
-    is a static Python unroll so peak memory stays O(Q*P), never O(Q*K*P).
+    gathered from the resident planes by row id; ``(-inf, +inf)``
+    constraints are padding no-ops (tv=2, the AND identity).  With
+    ``limbs > 1`` each constraint is ``limbs`` consecutive (row, lo, hi)
+    slots compared lexicographically, row -1 reading 0.  The K loop is a
+    static Python unroll so peak memory stays O(Q*P), never O(Q*K*P).
     """
-    Q, Kb = lo.shape
+    Q, KL = lo.shape
     P = mins.shape[1]
+
+    def take(plane, c):
+        if limbs == 1:
+            return jnp.take(plane, c, axis=0)           # [Q, P]
+        got = jnp.take(plane, jnp.maximum(c, 0), axis=0)
+        return jnp.where((c >= 0)[:, None], got, 0.0)
+
     tv = jnp.full((Q, P), 2, dtype=jnp.int32)
-    for k in range(Kb):
-        pmin = jnp.take(mins, cids[:, k], axis=0)       # [Q, P]
-        pmax = jnp.take(maxs, cids[:, k], axis=0)
-        pdem = jnp.take(demote, cids[:, k], axis=0)
-        lo_k = lo[:, k][:, None]
-        hi_k = hi[:, k][:, None]
-        empty = pmin > pmax
-        no = (pmax < lo_k) | (pmin > hi_k) | empty
-        full = (pmin >= lo_k) & (pmax <= hi_k) & (pdem == 0.0) & ~empty
-        tv_k = jnp.where(no, 0, jnp.where(full, 2, 1)).astype(jnp.int32)
-        noop = (lo_k == -jnp.inf) & (hi_k == jnp.inf)
-        tv_k = jnp.where(noop, 2, tv_k)
-        tv = jnp.minimum(tv, tv_k)
+    for k in range(KL // limbs):
+        js = range(k * limbs, (k + 1) * limbs)
+        rows = [(take(mins, cids[:, j]), take(maxs, cids[:, j])) for j in js]
+        rel = _relations(rows, [lo[:, j][:, None] for j in js],
+                         [hi[:, j][:, None] for j in js])
+        j0 = k * limbs
+        tv = _combine(tv, rel, take(demote, cids[:, j0]),
+                      lo[:, j0][:, None], hi[:, j0][:, None])
     return tv
 
 
-def minmax_prune_gathered_ref(cids, lo, hi, mins, maxs, demote, pos
-                              ) -> jax.Array:
+def minmax_prune_gathered_ref(cids, lo, hi, mins, maxs, demote, pos,
+                              limbs: int = 1) -> jax.Array:
     """tv [Q, W] int32 over per-query *gathered* plane positions.
 
     The tree path's survivor-restricted evaluator: column w of row q is
@@ -59,28 +97,30 @@ def minmax_prune_gathered_ref(cids, lo, hi, mins, maxs, demote, pos
     entry (q, w) equals ``minmax_prune_batched_ref(...)[q, pos[q, w]]``
     bit-for-bit — the gather commutes with every elementwise step of the
     tri-valued conjunction.  Duplicate or padding positions simply
-    recompute the same truthful verdict.
+    recompute the same truthful verdict.  ``limbs`` as in
+    ``minmax_prune_batched_ref``.
     """
-    Q, Kb = lo.shape
+    Q, KL = lo.shape
     stride = mins.shape[1]
     fm = mins.reshape(-1)
     fx = maxs.reshape(-1)
     fd = demote.reshape(-1)
+
+    def take(flat, c):
+        if limbs == 1:
+            return jnp.take(flat, c[:, None] * stride + pos)   # [Q, W]
+        got = jnp.take(flat, jnp.maximum(c, 0)[:, None] * stride + pos)
+        return jnp.where((c >= 0)[:, None], got, 0.0)
+
     tv = jnp.full(pos.shape, 2, dtype=jnp.int32)
-    for k in range(Kb):
-        idx = cids[:, k][:, None] * stride + pos        # [Q, W] flat index
-        pmin = jnp.take(fm, idx)
-        pmax = jnp.take(fx, idx)
-        pdem = jnp.take(fd, idx)
-        lo_k = lo[:, k][:, None]
-        hi_k = hi[:, k][:, None]
-        empty = pmin > pmax
-        no = (pmax < lo_k) | (pmin > hi_k) | empty
-        full = (pmin >= lo_k) & (pmax <= hi_k) & (pdem == 0.0) & ~empty
-        tv_k = jnp.where(no, 0, jnp.where(full, 2, 1)).astype(jnp.int32)
-        noop = (lo_k == -jnp.inf) & (hi_k == jnp.inf)
-        tv_k = jnp.where(noop, 2, tv_k)
-        tv = jnp.minimum(tv, tv_k)
+    for k in range(KL // limbs):
+        js = range(k * limbs, (k + 1) * limbs)
+        rows = [(take(fm, cids[:, j]), take(fx, cids[:, j])) for j in js]
+        rel = _relations(rows, [lo[:, j][:, None] for j in js],
+                         [hi[:, j][:, None] for j in js])
+        j0 = k * limbs
+        tv = _combine(tv, rel, take(fd, cids[:, j0]),
+                      lo[:, j0][:, None], hi[:, j0][:, None])
     return tv
 
 

@@ -122,19 +122,25 @@ class ServiceCounters:
     tree_launches: int = 0     # launches that ran the hierarchical path
     # per-technique attribution: {'filter': {'launches': n, 'fallbacks': m}};
     # 'join_bloom' also counts Bloom summaries whose filter a matcher
-    # read ('built') or that finished the join stage without it ('deferred')
+    # read ('built') or that finished the join stage without it
+    # ('deferred'); 'filter' also counts launched constraints compared
+    # exactly on a wide column's limbs ('wide') and those whose bounds
+    # f32 could not hold, so FULL was demoted ('demoted')
     technique: Dict[str, Dict[str, int]] = dataclasses.field(
         default_factory=dict)
 
     def bump(self, tech: str, launches: int = 0, fallbacks: int = 0,
              sharded: int = 0, tree: int = 0, built: int = 0,
-             deferred: int = 0) -> None:
+             deferred: int = 0, wide: int = 0, demoted: int = 0) -> None:
         t = self.technique.setdefault(tech, dict(launches=0, fallbacks=0))
         t["launches"] += launches
         t["fallbacks"] += fallbacks
         if built or deferred:
             t["built"] = t.get("built", 0) + built
             t["deferred"] = t.get("deferred", 0) + deferred
+        if wide or demoted:
+            t["wide"] = t.get("wide", 0) + wide
+            t["demoted"] = t.get("demoted", 0) + demoted
         self.launches += launches
         self.host_fallbacks += fallbacks
         self.sharded_launches += sharded
@@ -447,7 +453,8 @@ class PruningService:
                             range_lists, dstats, self.mode, mesh=mesh)
                     self.counters.bump("filter", launches=1,
                                        sharded=self._sharded(),
-                                       tree=self._tree_ran(tree))
+                                       tree=self._tree_ran(tree),
+                                       **kops.last_pack_stats())
                 return tv
             return thunk
 

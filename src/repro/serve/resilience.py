@@ -85,9 +85,11 @@ COUNTER_REGISTRY = frozenset({
     "verifications", "checksum_failures", "quarantines",
     # per-technique attribution (ServiceCounters.bump / .technique);
     # built / deferred: Bloom summaries whose filter was or was never
-    # materialized by the join stage (join_bloom only)
+    # materialized by the join stage (join_bloom only); wide / demoted:
+    # launched constraints compared on a wide column's exact limbs, and
+    # those whose bounds f32 could not hold (filter only)
     "filter", "join", "join_bloom", "topk", "launches", "fallbacks",
-    "built", "deferred",
+    "built", "deferred", "wide", "demoted",
     # report sections attached to each batch (PruningService.run_batch)
     "technique", "staging", "memory", "resilience", "integrity", "planes",
     # latency/SLO counters (new_latency_counters; serve.frontend attaches

@@ -39,8 +39,12 @@ SPANS = (
     "rung.verdict", "rung.sharded_tree", "rung.tree", "rung.sharded",
     "rung.device", "rung.host_kernel", "rung.host_oracle",
     "rung.passthrough", "backoff",
-    # resident planes (core/device_stats.py)
-    "planes.stage", "planes.verify",
+    # LIMIT stage (core/flow.py): choosing the fully-matching partitions
+    # and the cut, once per LIMIT query
+    "limit.select",
+    # resident planes (core/device_stats.py); planes.limbs: the split of
+    # wide columns' stats into exact limbs, at stage and at replay
+    "planes.stage", "planes.verify", "planes.limbs",
     # batched kernel wrappers (kernels/ops.py): once per launch
     "pack", "dispatch", "device_wait", "d2h", "unpack",
 )

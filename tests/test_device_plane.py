@@ -3,6 +3,7 @@ vs the f64 host oracle; DeviceStatsCache staging/invalidation; the f32
 precision contract; the vectorized block-topk staging."""
 
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -154,13 +155,16 @@ class TestPrecisionContract:
         assert (lo.astype(np.float64) <= vals).all()
         assert (hi.astype(np.float64) >= vals).all()
 
-    def test_big_int_keys_never_false_no_match_or_full(self):
-        """int64 keys > 2**24: FULL may degrade to PARTIAL, NO_MATCH and
-        FULL are never falsely claimed (the regression the cast contract
-        guards)."""
+    @pytest.mark.parametrize("base, exact", [
+        (2**24, True), (2**53 - 4096, True), (2**54, False)])
+    def test_big_int_keys_never_false_no_match_or_full(self, base, exact):
+        """int64 keys > 2**24: up to 2**53 the batched path evaluates
+        them on exact limbs, bit-identical to the f64 oracle; beyond it
+        FULL may degrade to PARTIAL, but NO_MATCH and FULL are never
+        falsely claimed (the regression the cast contract guards).  The
+        per-query path keeps the widening contract throughout."""
         P, C = 64, 2
         rng = np.random.default_rng(5)
-        base = 2**24
         mins = (base + rng.integers(0, 1000, size=(P, C))).astype(np.float64)
         maxs = mins + rng.integers(0, 9, size=(P, C)).astype(np.float64)
         stats = PartitionStats(
@@ -170,6 +174,7 @@ class TestPrecisionContract:
             row_counts=np.full(P, 10, dtype=np.int64),
         )
         dstats = DeviceStats.stage(stats)
+        assert bool(dstats.wide) == exact
         range_lists = []
         for _ in range(32):
             lo = float(base + rng.integers(0, 1000))
@@ -185,9 +190,12 @@ class TestPrecisionContract:
                 assert ((got == NO_MATCH) <= (oracle == NO_MATCH)).all()
                 # never a false FULL: FULL only where the oracle proves it
                 assert ((got == FULL_MATCH) <= (oracle == FULL_MATCH)).all()
+            if exact:
+                np.testing.assert_array_equal(tv[qi], oracle)
             some_demotion |= bool((tv[qi] != oracle).any())
-        # the contract is exercised: at least one FULL degraded to PARTIAL
-        assert some_demotion
+        # beyond 2**53 the contract is exercised: at least one FULL
+        # degraded to PARTIAL
+        assert some_demotion != exact
 
     def test_infinite_float_stats_safe_on_kernel_path(self):
         """Float columns holding real ±inf values: the finite clamp must

@@ -198,3 +198,43 @@ class TestTreePrepassOracleParity:
             np.testing.assert_array_equal(
                 tv_tree[qi], res.tv,
                 err_msg=f"q={qi}: device tree vs cutoff-free host tree")
+
+
+class TestTreeLaunchShapes:
+    """The tree rung's gathered launches keep one shape per level for a
+    table geometry and query bucket, however many groups survive and
+    however many ranges the queries carry: a shape that followed either
+    would compile inside a serving window."""
+
+    def test_survivors_and_range_counts_share_one_shape(self):
+        rng = np.random.default_rng(0)
+        P, C = 1024, 3
+        mins = np.sort(rng.integers(0, 100_000, (P, C)), axis=0).astype(
+            np.float64)
+        maxs = mins + rng.integers(0, 50, (P, C))
+        stats = PartitionStats(
+            columns=[ColumnMeta(f"c{i}", "int") for i in range(C)],
+            mins=mins, maxs=maxs,
+            null_counts=np.zeros((P, C), dtype=np.int64),
+            row_counts=np.full(P, 5, dtype=np.int64))
+        dstats = DeviceStats.stage(stats, capacity=plane_capacity(P))
+        tree = tree_entry_for(dstats, fanout=16)
+
+        def batch(width, k):
+            out = []
+            for _ in range(8):
+                lo = float(rng.integers(0, 100_000 - width))
+                out.append([(c, lo, lo + width) for c in range(k)])
+            return out
+
+        cached = []
+        for width in (100, 2_000, 8_000):
+            for k in (1, 3):
+                b = batch(width, k)
+                tv = ops.prune_ranges_batched_tree(b, dstats, tree,
+                                                   mode="ref")
+                assert ops.last_tree_stats()["path"] == "tree"
+                np.testing.assert_array_equal(
+                    tv, ops.prune_ranges_batched_host(b, stats))
+                cached.append(ops._gathered_ref_jit._cache_size())
+        assert cached[-1] == cached[0]

@@ -80,6 +80,18 @@ def test_minmax_prune_batched_compiles(one_chip, Q):
               S((C, P), F32), S((C, P), F32), S((C, P), F32)))
 
 
+@pytest.mark.parametrize("limbs", [2, 3])
+def test_minmax_prune_batched_compiles_on_limbs(one_chip, limbs):
+    """A launch with a wide column: ``limbs`` slots per constraint and
+    the column's limb rows after the six primary ones."""
+    S = _spec(one_chip)
+    Q, Kb, rows = ops.q_bucket(64), 4, C + limbs
+    _compile(minmax_prune_batched,
+             (S((Q, Kb * limbs), I32), S((Q, Kb * limbs), F32),
+              S((Q, Kb * limbs), F32), S((rows, P), F32),
+              S((rows, P), F32), S((rows, P), F32)), limbs=limbs)
+
+
 @pytest.mark.parametrize("Db", [ops.d_bucket(1), ops.d_bucket(4096)])
 @pytest.mark.parametrize("Q", Q_BUCKETS)
 def test_join_overlap_batched_compiles(one_chip, Q, Db):
