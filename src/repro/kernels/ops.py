@@ -386,6 +386,41 @@ def last_pack_stats() -> dict:
     return getattr(_pack_note, "d", {})
 
 
+# The flat launch's verdict copy spans the live partitions rounded up to
+# one of this many granules of the plane's capacity: a handful of widths
+# per capacity, one for a fixed P, and appends within a granule reuse it.
+VERDICT_GRANULES = 8
+
+
+def verdict_width(P: int, Pc: int) -> int:
+    """Columns of the flat launch's verdicts copied to the host: ``P``
+    rounded up to a multiple of ``Pc // VERDICT_GRANULES``, at most
+    ``Pc``, then to whole 4-byte words."""
+    g = max(1, Pc // VERDICT_GRANULES)
+    return -(-min(Pc, -(-max(P, 1) // g) * g) // 4) * 4
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def _narrow_verdicts(tv, full_safe, width: int):
+    """The flat launch's epilogue, on the device: FULL demoted to PARTIAL
+    on the rows whose bounds f32 could not hold, the partition axis cut
+    (or, past a capacity that is not whole words, padded with NO_MATCH)
+    to ``width``, int32 narrowed to int8 — the form the host consumes,
+    which views the result back as ``[Qb, width]`` int8.  The int8 bytes
+    travel packed four to an int32 word: the TPU copies int8 arrays to
+    the host at about a third of int32's rate per byte.
+
+    A program of its own, not one with the filter's: fused with the jnp
+    oracle or the interpreted kernel into one program, an int8 result
+    corrupted the heap of XLA's CPU backend."""
+    tv = tv[:, :width]
+    tv = jnp.pad(tv, ((0, 0), (0, width - tv.shape[1])))
+    tv = jnp.where(full_safe[:, None], tv,
+                   jnp.minimum(tv, 1)).astype(jnp.int8)
+    return jax.lax.bitcast_convert_type(
+        tv.reshape(tv.shape[0], width // 4, 4), jnp.int32)
+
+
 _batched_ref_jit = jax.jit(ref.minmax_prune_batched_ref,
                            static_argnames=("limbs",))
 
@@ -423,6 +458,11 @@ def prune_ranges_batched_device(
     shard on the capacity dim: each device evaluates its partition slice
     and the verdict rows concatenate — bit-identical to the unsharded
     launch (partitions are independent).
+
+    Every rung leaves the device as ``[Qb, verdict_width(P, Pc)]`` int8
+    (packed in int32 words) with the FULL demotion applied, and the
+    result is a view of that copy, read-only but for the slabbed jnp
+    path's.
     """
     Q = len(range_lists)
     with span("pack", q=Q):
@@ -431,14 +471,15 @@ def prune_ranges_batched_device(
         # can never mix post-DML planes with a pre-DML partition count (or
         # vice versa)
         planes, P = dstats.planes_state
-        mins, maxs, demote = planes
-        Pc = int(mins.shape[1])        # staged capacity (>= P; sentinel tail)
+        Pc = int(planes[0].shape[1])   # staged capacity (>= P; sentinel tail)
+        W = verdict_width(P, Pc)
         _c, _l, _h, full_safe, (rows, lo, hi, limbs) = pack_ranges(
             range_lists, dstats)
         Qb = rows.shape[0]
-        cids_d = jnp.asarray(rows)
-        lo_d = jnp.asarray(lo)
-        hi_d = jnp.asarray(hi)
+        safe = np.ones(Qb, dtype=bool)  # padding rows are sliced off
+        safe[:Q] = full_safe
+        args = (jnp.asarray(rows), jnp.asarray(lo), jnp.asarray(hi))
+        safe_d = jnp.asarray(safe)
     shards = mesh_shards(mesh, Pc)
     if (shards > 1 and not _use_kernel(mode)
             and Qb * Pc // shards > _REF_SLAB_ELEMS):
@@ -448,30 +489,29 @@ def prune_ranges_batched_device(
     with span("dispatch", q=Q, P=P):
         if shards > 1:
             fn = _sharded_minmax(mesh, *_body_flags(mode), limbs)
-            out = fn(cids_d, lo_d, hi_d, mins, maxs, demote)
-        elif mode == "ref" or (mode == "auto" and not _on_tpu()):
+            out = _narrow_verdicts(fn(*args, *planes), safe_d, width=W)
+        elif _use_kernel(mode):
+            out = _narrow_verdicts(
+                minmax_prune_batched(*args, *planes,
+                                     interpret=kernel_interpret(mode),
+                                     limbs=limbs), safe_d, width=W)
+        else:
             slab = max(1024, _REF_SLAB_ELEMS // Qb)
             if slab >= Pc:
-                out = _batched_ref_jit(cids_d, lo_d, hi_d, mins, maxs, demote,
-                                       limbs=limbs)
+                out = _narrow_verdicts(
+                    _batched_ref_jit(*args, *planes, limbs=limbs), safe_d,
+                    width=W)
             else:
-                out = [_batched_ref_jit(
-                    cids_d, lo_d, hi_d,
-                    jax.lax.slice_in_dim(mins, s, min(s + slab, Pc), axis=1),
-                    jax.lax.slice_in_dim(maxs, s, min(s + slab, Pc), axis=1),
-                    jax.lax.slice_in_dim(demote, s, min(s + slab, Pc), axis=1),
-                    limbs=limbs)
-                    for s in range(0, Pc, slab)]
-        else:
-            out = minmax_prune_batched(cids_d, lo_d, hi_d, mins, maxs, demote,
-                                       interpret=kernel_interpret(mode),
-                                       limbs=limbs)
+                out = []
+                for s in range(0, W, slab):   # past W: sentinel tail only
+                    cut = [jax.lax.slice_in_dim(a, s, min(s + slab, Pc),
+                                                axis=1) for a in planes]
+                    out.append(_narrow_verdicts(
+                        _batched_ref_jit(*args, *cut, limbs=limbs), safe_d,
+                        width=min(slab, W - s)))
     tv = _fetch(out)
     with span("unpack", q=Q, P=P):
-        tv = tv[:Q, :P].astype(np.int8)
-        if not full_safe.all():
-            tv[~full_safe] = np.minimum(tv[~full_safe], 1)
-    return tv
+        return tv.view(np.int8)[:Q, :P]
 
 
 def prune_ranges_batched_host(

@@ -1,6 +1,12 @@
 """Device metadata plane: batched multi-query kernel vs per-query kernel
-vs the f64 host oracle; DeviceStatsCache staging/invalidation; the f32
-precision contract; the vectorized block-topk staging."""
+vs the f64 host oracle; the flat launch's int8 verdict copy;
+DeviceStatsCache staging/invalidation; the f32 precision contract; the
+vectorized block-topk staging."""
+
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -12,7 +18,8 @@ import jax.numpy as jnp
 from repro.core import expr as E
 from repro.core.device_stats import (DeviceStats, DeviceStatsCache,
                                      cast_bounds_f32, cast_stats_f32,
-                                     round_down_f32, round_up_f32)
+                                     plane_capacity, round_down_f32,
+                                     round_up_f32)
 from repro.core.flow import PruningPipeline, Query, TableScanSpec
 from repro.core.metadata import (FULL_MATCH, NO_MATCH, ColumnMeta,
                                  PartitionStats)
@@ -143,6 +150,145 @@ class TestBatchedKernelParity:
         tv = ops.prune_ranges_batched_device(range_lists, dstats, mode="ref")
         for qi, ranges in enumerate(range_lists):
             np.testing.assert_array_equal(tv[qi], eval_ranges_tv(ranges, tbl.stats))
+
+
+def flat_contract_problem(Q, seed=0, P=3000):
+    """Stats below their plane's capacity (a sentinel tail), two int
+    columns and a float one whose values f32 holds exactly, and Q queries:
+    the even ones carry a float-column range whose bounds f32 cannot hold
+    (``full_safe`` false: FULL is demoted to PARTIAL), the odd ones int
+    ranges only."""
+    rng = np.random.default_rng(seed)
+    base = make_stats(P, 3, rng)
+    halve = np.array([1.0, 1.0, 0.5])
+    stats = PartitionStats(
+        columns=[ColumnMeta("c0", "int"), ColumnMeta("c1", "int"),
+                 ColumnMeta("c2", "float")],
+        mins=base.mins * halve, maxs=base.maxs * halve,
+        null_counts=base.null_counts, row_counts=base.row_counts)
+    range_lists = []
+    for qi in range(Q):
+        lo = float(rng.integers(-1100, 1100))
+        ranges = [(int(rng.integers(0, 2)), lo,
+                   lo + float(rng.integers(0, 300)))]
+        if qi % 2 == 0:
+            lo = float(rng.integers(-550, 550)) + 0.1
+            ranges.append((2, lo, lo + float(rng.integers(100, 400)) + 0.2))
+        range_lists.append(ranges)
+    return stats, range_lists
+
+
+def check_flat_contract(Q, rung, modes=("ref", "interpret")):
+    """The flat wrapper's result contract on ``rung`` (``device``,
+    ``slabs``: the memory-bounded jnp path, ``sharded``: a plane mesh of
+    every host device, ``dense``: planes staged at exactly an odd P, so
+    the copy's whole words pass the capacity): int8 ``[Q, P]``,
+    bit-identical to the host kernel with FULL demoted where ``full_safe``
+    is false, and a ``d2h`` copy of ``Qb x W`` bytes.  Returns the shard
+    count each mode ran on."""
+    from repro.launch.mesh import make_plane_mesh
+    dense = rung == "dense"
+    stats, range_lists = flat_contract_problem(Q, P=3001 if dense else 3000)
+    P = stats.num_partitions
+    dstats = DeviceStats.stage(stats,
+                               capacity=None if dense else plane_capacity(P))
+    Pc = dstats.capacity
+    W = ops.verdict_width(P, Pc)
+    assert W % 4 == 0 and (Pc < W if dense else P < W < Pc)
+    safe = ops.pack_ranges(range_lists, dstats)[3]
+    host = ops.prune_ranges_batched_host(range_lists, stats)
+    want = np.where(safe[:, None], host, np.minimum(host, 1))
+    assert (~safe).any() and (host[~safe] == FULL_MATCH).any()
+    mesh = make_plane_mesh() if rung == "sharded" else None
+    recorded = []
+    real_span = ops.span
+
+    def span(name, **meta):
+        recorded.append((name, meta))
+        return real_span(name, **meta)
+
+    shards = {}
+    for mode in modes:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(ops, "span", span)
+            if rung == "slabs":     # slabs of 1,280 columns, the last cut
+                mp.setattr(ops, "_REF_SLAB_ELEMS", 1280 * ops.q_bucket(Q))
+            recorded.clear()
+            tv = ops.prune_ranges_batched_device(range_lists, dstats,
+                                                 mode=mode, mesh=mesh)
+        shards[mode] = ops.last_launch_shards()
+        assert tv.dtype == np.int8 and tv.shape == (Q, P), mode
+        np.testing.assert_array_equal(tv, want, err_msg=mode)
+        d2h = [meta for name, meta in recorded if name == "d2h"]
+        assert d2h == [dict(bytes=ops.q_bucket(Q) * W)], mode
+    return shards
+
+
+FLAT_RUNGS = ("device", "slabs", "sharded", "dense")
+
+
+class TestFlatVerdictContract:
+    """The flat launch leaves the device as int8 over the live partitions
+    (rounded up to a granule of the capacity), with the FULL demotion
+    already applied, on every rung."""
+
+    @pytest.mark.parametrize("rung", FLAT_RUNGS)
+    @pytest.mark.parametrize("Q", [1, 5, 64])
+    def test_int8_live_verdicts_equal_the_host_kernel(self, Q, rung):
+        if rung != "sharded":
+            check_flat_contract(Q, rung, ("ref",) if rung == "slabs"
+                                else ("ref", "interpret"))
+            return
+        # the sharded rung on two forced CPU devices, in a process of
+        # its own (the suite's backend keeps whatever devices it found)
+        here = os.path.dirname(os.path.abspath(__file__))
+        root = os.path.dirname(here)
+        code = ("import json, sys\n"
+                f"sys.path[:0] = [{here!r}]\n"
+                "import test_device_plane as t\n"
+                f"print(json.dumps(t.check_flat_contract({Q}, 'sharded')))\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=2",
+                   PYTHONPATH=os.pathsep.join([os.path.join(root, "src"),
+                                               root]))
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, env=env, cwd=root, timeout=600)
+        assert r.returncode == 0, r.stderr[-4000:]
+        shards = json.loads(r.stdout.strip().splitlines()[-1])
+        assert shards == {"ref": 2, "interpret": 2}
+
+
+class TestFlatLaunchShapes:
+    """The epilogue's width depends only on P and the capacity, so a
+    fixed P reuses one program and P may grow within its granule without
+    compiling: a width that followed P would compile inside a window."""
+
+    def test_fixed_and_growing_P_reuse_one_program(self):
+        stats, _ = flat_contract_problem(8, seed=1, P=3100)
+        rng = np.random.default_rng(2)
+
+        def stage(P):
+            sub = PartitionStats(
+                columns=stats.columns, mins=stats.mins[:P],
+                maxs=stats.maxs[:P], null_counts=stats.null_counts[:P],
+                row_counts=stats.row_counts[:P])
+            return DeviceStats.stage(sub, capacity=4096)
+
+        def launch(dstats):
+            batch = []
+            for _ in range(8):
+                lo = float(rng.integers(-1100, 1100))
+                batch.append([(0, lo, lo + 200.0), (1, lo - 50.0, lo)])
+            ops.prune_ranges_batched_device(batch, dstats, mode="ref")
+            return ops._narrow_verdicts._cache_size()
+
+        first = stage(2600)
+        assert ops.verdict_width(2600, 4096) == 3072
+        sizes = [launch(first) for _ in range(3)]
+        grown = stage(3000)                   # the same granule
+        sizes += [launch(grown) for _ in range(2)]
+        assert sizes == [sizes[0]] * 5
+        assert launch(stage(3100)) == sizes[0] + 1   # the next granule
 
 
 class TestPrecisionContract:
@@ -352,19 +498,43 @@ class TestPruningService:
             Query(scans={"t": TableScanSpec(t)}),                 # TruePred
         ]
 
-    def test_batch_equals_host_pipeline(self):
+    def test_batch_equals_host_pipeline(self, monkeypatch):
+        """Also with dropped partitions, a LIMIT query and the verdict
+        cache serving rows: the flat launch hands read-only int8 views
+        downstream, and nothing there writes into them (a write would
+        raise and demote the rung, which ``last_errors`` would show)."""
         t, u = self._tables()
-        queries = self._queries(t, u)
+        t.drop_partitions([3, 17, 30])
+        queries = self._queries(t, u) + [
+            Query(scans={"t": TableScanSpec(t, E.col("w") >= 3000)},
+                  limit=7)]
+        flat = ops.prune_ranges_batched_device
+        writeable = []
+
+        def recording(*args, **kw):
+            tv = flat(*args, **kw)
+            writeable.append(tv.flags.writeable)
+            return tv
+
+        monkeypatch.setattr(ops, "prune_ranges_batched_device", recording)
         svc = PruningService(mode="ref")
-        reports = svc.run_batch(queries)
         host = PruningPipeline(filter_mode="host")
-        for q, rep in zip(queries, reports):
-            h = host.run(q)
-            for name in q.scans:
-                np.testing.assert_array_equal(
-                    rep.scan_sets[name].part_ids, h.scan_sets[name].part_ids)
-                np.testing.assert_array_equal(
-                    rep.scan_sets[name].match, h.scan_sets[name].match)
+        for _ in range(3):          # seen, recorded, then served
+            reports = svc.run_batch(queries)
+            for q, rep in zip(queries, reports):
+                h = host.run(q)
+                for name in q.scans:
+                    np.testing.assert_array_equal(
+                        rep.scan_sets[name].part_ids,
+                        h.scan_sets[name].part_ids)
+                    np.testing.assert_array_equal(
+                        rep.scan_sets[name].match, h.scan_sets[name].match)
+        assert len(reports[-1].scan_sets["t"]) < len(
+            svc.prune_batch(queries[-1:])[0]["t"])      # LIMIT cut
+        assert writeable and not any(writeable)
+        assert svc.ladder.last_errors == {}
+        assert svc.counters.tree_launches == 0
+        assert svc.resilience["verdict_hits"] > 0
 
     def test_one_launch_per_table_group(self):
         t, u = self._tables()

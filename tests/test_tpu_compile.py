@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.device_stats import KPLANE
+from repro.core.device_stats import KPLANE, plane_capacity
 from repro.core.prune_join import BLOCK_WORDS, DEFAULT_ENUM_LIMIT
 from repro.kernels import (bloom_probe_batched, join_overlap_batched,
                            minmax_prune_batched, ops, topk_init_batched)
@@ -78,6 +78,21 @@ def test_minmax_prune_batched_compiles(one_chip, Q):
     _compile(minmax_prune_batched,
              (S((Q, Kb), I32), S((Q, Kb), F32), S((Q, Kb), F32),
               S((C, P), F32), S((C, P), F32), S((C, P), F32)))
+
+
+@pytest.mark.parametrize("Q", Q_BUCKETS)
+def test_flat_verdict_epilogue_compiles(one_chip, Q):
+    """The flat launch's epilogue at the events table's size: the
+    kernel's int32 verdicts over the plane's capacity (2**21 for
+    P = 2**20) in, int8 over the live partitions out, four to a word."""
+    S = _spec(one_chip)
+    Pc = plane_capacity(P)
+    W = ops.verdict_width(P, Pc)
+    assert W == P < Pc
+    text = ops._narrow_verdicts.lower(
+        S((Q, Pc), I32), S((Q,), jnp.bool_), width=W).compile().as_text()
+    entry = next(line for line in text.splitlines() if "ENTRY" in line)
+    assert entry.endswith(f"-> s32[{Q},{W // 4}] {{"), entry
 
 
 @pytest.mark.parametrize("limbs", [2, 3])
