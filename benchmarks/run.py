@@ -91,7 +91,7 @@ def main() -> None:
     # Modules that write their own artifact (EMITS_OWN_JSON) resolve its
     # location from this env var, so --json-dir governs them too.
     os.environ["BENCH_JSON_DIR"] = args.json_dir
-    from repro.launch.compile_cache import use_compile_cache
+    from repro.compile_cache import use_compile_cache
     use_compile_cache(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 

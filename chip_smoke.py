@@ -465,7 +465,7 @@ def main(argv=None) -> int:
         print(f"chip_smoke: the engine is not importable next to this "
               f"script: {exc}", file=sys.stderr)
         return 2
-    from repro.launch.compile_cache import use_compile_cache
+    from repro.compile_cache import use_compile_cache
     import jax
 
     devs = jax.devices()

@@ -1,13 +1,9 @@
-"""Serving-side example: batched top-k analytics + LM decode behind one
-stack.
+"""Top-k analytics over a logged-requests table.
 
-Production serving deployments carry an analytics sidecar (request logs,
-feature stores) — exactly the workload the paper's top-k pruning (Sec. 5)
-accelerates.  This example:
-  1. serves batched `ORDER BY score DESC LIMIT k` queries over a logged-
-     requests table with boundary-value pruning (vs. the full scan), and
-  2. runs a small LM through prefill+decode with the same Generator the
-     dry-run's decode shapes lower.
+Serving deployments keep request logs, and `ORDER BY ... LIMIT k` over
+them is the workload the paper's top-k pruning (Sec. 5) accelerates.
+This example serves such queries with boundary-value pruning and
+compares the bytes read against the full scan.
 
 Run:  PYTHONPATH=src python examples/topk_serving.py
 """
@@ -16,18 +12,13 @@ import time
 
 import numpy as np
 
-from repro.configs import get_smoke_config
 from repro.core import expr as E
 from repro.core.flow import PruningPipeline, Query, TableScanSpec
 from repro.data.generator import ColumnSpec, gen_table
 from repro.data.scan import execute_query
-from repro.models import build_model
-from repro.models.sharding import init_params
-from repro.serve.serve_step import Generator
 
 rng = np.random.default_rng(0)
 
-# ---- 1. the analytics sidecar: top-k over logged requests -----------------
 requests = gen_table(
     "requests", rng, n_rows=200_000, rows_per_partition=1000,
     specs=[
@@ -56,17 +47,8 @@ for name, q in queries:
     base = execute_query(q, None)
     t = rep.per_scan["requests"].get("topk")
     skipped = len(rep.topk.skipped) if rep.topk is not None else 0
-    print(f"[analytics] {name}: {skipped} of "
+    print(f"{name}: {skipped} of "
           f"{t.before if t else '?'} partitions skipped "
           f"({res.total_bytes()/1e6:.1f} MB vs {base.total_bytes()/1e6:.1f} MB "
           f"unpruned) in {dt:.0f} ms")
 
-# ---- 2. the LM behind it: batched prefill + decode -------------------------
-cfg = get_smoke_config("llama3.2-3b")
-model = build_model(cfg)
-import jax
-params = init_params(model.specs, jax.random.PRNGKey(0))
-gen = Generator(model, params, max_seq=64)
-prompts = np.array([[1, 5, 9, 13, 17, 21, 25, 29]] * 4)  # batch of 4
-out = gen.generate(prompts, steps=16)
-print(f"[serving] decoded {out.shape} tokens; sample: {out[0][:8].tolist()}")

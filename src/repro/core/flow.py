@@ -553,7 +553,7 @@ class PruningPipeline:
         shard_planes: bool = False,  # partition-shard the lazily-built
                                      # service's batched launches over the
                                      # host plane mesh (shard_map on
-                                     # launch.mesh.make_plane_mesh()).
+                                     # kernels.ops.make_plane_mesh()).
         tree_fanout: Optional[int] = None,
                                      # hierarchical-plane group size for the
                                      # lazily-built service (None keeps the

@@ -13,8 +13,6 @@ Pruning hot spots (the paper's engine):
   bloom_probe_batched  — Q blocked-Bloom filters x P probe partitions in one
                          launch: narrow-range enumeration against the
                          resident enumeration plane (Sec. 6, large-NDV path)
-LM hot spot:
-  flash_attention      — causal online-softmax attention (prefill compute)
 
 Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
 validated on CPU with interpret=True against the pure-jnp oracles in
@@ -23,7 +21,6 @@ ref.py.
 
 from . import ops, ref
 from .bloom_probe import bloom_probe_batched
-from .flash_attention import flash_attention
 from .join_overlap import join_overlap, join_overlap_batched
 from .minmax_prune import minmax_prune
 from .minmax_prune_batched import minmax_prune_batched
@@ -31,4 +28,4 @@ from .topk_boundary import topk_boundary, topk_init_batched
 
 __all__ = ["ops", "ref", "minmax_prune", "minmax_prune_batched",
            "topk_boundary", "topk_init_batched", "join_overlap",
-           "join_overlap_batched", "bloom_probe_batched", "flash_attention"]
+           "join_overlap_batched", "bloom_probe_batched"]

@@ -85,7 +85,7 @@ def kernel_interpret(mode: str) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Partition-dim sharding (fleet-scale planes; launch/mesh.make_plane_mesh)
+# Partition-dim sharding (fleet-scale planes; make_plane_mesh)
 # ---------------------------------------------------------------------------
 #
 # Every batched kernel evaluates queries x partitions with no cross-
@@ -99,6 +99,25 @@ def kernel_interpret(mode: str) -> bool:
 # (tests/test_kernel_sentinels.py pins that for all four kernels).
 
 PLANE_AXIS = "parts"
+
+
+def make_plane_mesh():
+    """The host's devices as one 1-D ``PLANE_AXIS`` mesh: the
+    partition-shard mesh of the metadata-plane kernels.
+
+    The resident ``[C, P]`` planes split their partition (capacity) dim
+    over this axis via ``shard_map``, so a table's P can grow past one
+    device's memory.  Plane capacities are powers of two, so the axis is
+    the largest power-of-two prefix of the host's device set — every
+    capacity >= the axis size divides evenly and shards.  On a
+    single-device host the mesh is size 1 and the launch path stays
+    unsharded (same code, no shard_map).
+    """
+    devs = jax.devices()
+    n = 1
+    while n * 2 <= len(devs):
+        n *= 2
+    return jax.sharding.Mesh(np.asarray(devs[:n]), (PLANE_AXIS,))
 
 
 def mesh_shards(mesh, cap: int) -> int:
@@ -454,7 +473,7 @@ def prune_ranges_batched_device(
     integers beyond 2**53) demote FULL to PARTIAL — never a false
     NO_MATCH or false FULL (core.device_stats precision contract).
 
-    With ``mesh`` (``launch.mesh.make_plane_mesh``) the resident planes
+    With ``mesh`` (``make_plane_mesh``) the resident planes
     shard on the capacity dim: each device evaluates its partition slice
     and the verdict rows concatenate — bit-identical to the unsharded
     launch (partitions are independent).

@@ -280,16 +280,3 @@ def topk_init_batched_ref(plane, mask, k: int) -> jax.Array:
         flat = jnp.pad(flat, ((0, 0), (0, k - flat.shape[1])),
                        constant_values=-jnp.inf)
     return jax.lax.top_k(flat, k)[0]
-
-
-def flash_attention_ref(q, k, v, causal: bool = True) -> jax.Array:
-    """Naive softmax attention oracle: q/k/v [BH, S, D]."""
-    D = q.shape[-1]
-    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (D ** -0.5)
-    if causal:
-        Sq, Sk = q.shape[1], k.shape[1]
-        mask = jnp.arange(Sk)[None, :] <= jnp.arange(Sq)[:, None]
-        s = jnp.where(mask[None], s, -1e30)
-    w = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", w, v.astype(jnp.float32)).astype(q.dtype)

@@ -44,7 +44,7 @@ under one HBM budget (``core.device_stats.PlaneMemoryManager`` — LRU
 eviction, in-flight pinning around each batched launch, hit / miss /
 eviction / restage-storm counters in ``counters["memory"]``), and
 ``shard_mesh`` partition-shards every batched launch over a 1-D device
-mesh (``launch.mesh.make_plane_mesh``) so a table's planes can outgrow
+mesh (``kernels.ops.make_plane_mesh``) so a table's planes can outgrow
 one device.  ``run_fleet`` drives a many-table workload — thousands of
 tables churning through the budget — and ``fleet_summary`` reports the
 budget-sizing view.
@@ -237,8 +237,7 @@ class PruningService:
                         f"re-budget to {budget_bytes}")
         self.cache = cache
         if shard_mesh is True:
-            from ..launch.mesh import make_plane_mesh
-            shard_mesh = make_plane_mesh()
+            shard_mesh = kops.make_plane_mesh()
         self.shard_mesh = shard_mesh
         self.versions: Dict[str, TableVersion] = {}
         self.counters = ServiceCounters()

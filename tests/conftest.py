@@ -11,10 +11,9 @@ sys.path.insert(0, os.path.dirname(__file__))
 # Multi-device CPU for the sharded-plane tests: REPRO_CPU_DEVICES=n
 # forces n host devices before jax's backend initializes, so shard_map
 # really runs multi-device (the CI fleet lane sets 8).  Opt-in only —
-# the main suite keeps whatever device count the backend picks up
-# (several train-substrate tests encode it), and sharded tests skip
-# gracefully on a single device.  A pre-existing XLA_FLAGS device-count
-# setting is always respected.
+# the main suite keeps whatever device count the backend picks up, and
+# sharded tests skip gracefully on a single device.  A pre-existing
+# XLA_FLAGS device-count setting is always respected.
 _n_cpu = os.environ.get("REPRO_CPU_DEVICES", "0")
 _flags = os.environ.get("XLA_FLAGS", "")
 if (_n_cpu.isdigit() and int(_n_cpu) > 0

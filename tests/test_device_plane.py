@@ -186,7 +186,7 @@ def check_flat_contract(Q, rung, modes=("ref", "interpret")):
     bit-identical to the host kernel with FULL demoted where ``full_safe``
     is false, and a ``d2h`` copy of ``Qb x W`` bytes.  Returns the shard
     count each mode ran on."""
-    from repro.launch.mesh import make_plane_mesh
+    from repro.kernels.ops import make_plane_mesh
     dense = rung == "dense"
     stats, range_lists = flat_contract_problem(Q, P=3001 if dense else 3000)
     P = stats.num_partitions

@@ -41,7 +41,7 @@ NDV_LIMIT = 8      # straddled by build sides: small -> distinct, big -> Bloom
 def _plane_mesh_or_none():
     if len(jax.devices()) < 2:
         return None
-    from repro.launch.mesh import make_plane_mesh
+    from repro.kernels.ops import make_plane_mesh
     return make_plane_mesh()
 
 

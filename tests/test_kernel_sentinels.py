@@ -189,7 +189,7 @@ def _shard_geometry():
     if len(jax.devices()) < 2:
         pytest.skip("sharded path needs >= 2 host devices "
                     "(REPRO_CPU_DEVICES forces them; '0' opts out)")
-    from repro.launch.mesh import make_plane_mesh
+    from repro.kernels.ops import make_plane_mesh
     mesh = make_plane_mesh()
     n = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
     cap = max(16, 4 * n)

@@ -231,7 +231,7 @@ def test_sharded_wrappers_span_their_launch_once():
     code = (
         "import json, sys\n"
         f"sys.path[:0] = [{os.path.join(ROOT, 'tests')!r}, {ROOT!r}]\n"
-        "from repro.launch.mesh import make_plane_mesh\n"
+        "from repro.kernels.ops import make_plane_mesh\n"
         "import test_spans\n"
         "print(json.dumps(test_spans.wrapper_span_counts("
         "'ref', mesh=make_plane_mesh())))\n")

@@ -178,7 +178,7 @@ def test_sharded_launch_bit_identical_to_the_oracle(mode):
     """The sharded body on a plane mesh of every host device (one unless
     REPRO_CPU_DEVICES forces more) evaluates the limbs as the flat
     launch does."""
-    from repro.launch.mesh import make_plane_mesh
+    from repro.kernels.ops import make_plane_mesh
     t = _table(seed=3)
     rng = np.random.default_rng(4)
     range_lists = [extract_ranges(p, t.stats) for p in _edge_preds(t, rng)]

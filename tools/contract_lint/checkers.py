@@ -366,9 +366,8 @@ class PrecisionContractChecker:
     name = "precision-contract"
 
     SCOPE = ("repro/core/", "repro/kernels/")
-    # the widening-helper home itself, and the model-side attention kernel
-    # (activations, not stats metadata — out of the contract's domain)
-    EXEMPT_SUFFIXES = ("core/device_stats.py", "kernels/flash_attention.py")
+    # the widening-helper home itself
+    EXEMPT_SUFFIXES = ("core/device_stats.py",)
 
     F32 = ("np.float32", "jnp.float32", "numpy.float32", "jax.numpy.float32")
     WIDENING = ("round_down_f32", "round_up_f32", "cast_stats_f32",
