@@ -297,8 +297,10 @@ class JoinTechnique(Technique):
         q = state.query
         bspec = q.scans[q.join.build]
         part_ids = state.scan_sets[q.join.build].part_ids
-        with span("join.build_keys", P=len(part_ids)):
-            bctx = bspec.table.ctx_for(part_ids)
+        cols = bspec.pred.columns() + (q.join.build_key,)
+        with span("join.build_keys", P=len(part_ids),
+                  **bspec.table.scan_meta(part_ids, cols)):
+            bctx = bspec.table.ctx_for(part_ids, columns=cols)
             bmask = matches(bspec.pred, bctx)
             keys, knulls = bctx.col(q.join.build_key)
             return keys[bmask & ~knulls]

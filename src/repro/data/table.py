@@ -32,7 +32,7 @@ the changed partitions instead of restaging ``[C, P]`` from scratch.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -88,15 +88,63 @@ class Table:
             {k: v[s] for k, v in self.nulls.items()},
         )
 
-    def ctx_for(self, part_ids: Sequence[int]) -> RowContext:
-        """RowContext over the concatenation of the given partitions."""
-        idx = np.concatenate(
-            [np.arange(self.part_bounds[p], self.part_bounds[p + 1]) for p in part_ids]
-        ) if len(part_ids) else np.zeros(0, dtype=np.int64)
+    def row_runs(self, part_ids: Sequence[int]) -> Tuple[np.ndarray, np.ndarray]:
+        """Row ranges ``[lo, hi)`` of the maximal runs of consecutive ids in
+        ``part_ids``, in its order: the partitions' rows, concatenated in
+        that order, are the rows of ``lo[0]:hi[0]``, then ``lo[1]:hi[1]``..."""
+        ids = np.asarray(part_ids, dtype=np.int64)
+        if ids.size == 0:
+            return ids, ids
+        cut = np.flatnonzero(np.diff(ids) != 1) + 1
+        first, last = ids[np.r_[0, cut]], ids[np.r_[cut - 1, ids.size - 1]]
+        return self.part_bounds[first], self.part_bounds[last + 1]
+
+    def scan_meta(self, part_ids: Sequence[int],
+                  columns: Sequence[str]) -> Dict[str, int]:
+        """What ``ctx_for(part_ids, columns)`` reads, as span metadata:
+        ``cols`` gathered, contiguous ``runs``, ``rows``, and ``bytes``
+        copied (0 for one run, which is a view)."""
+        cols = tuple(dict.fromkeys(columns))
+        lo, hi = self.row_runs(part_ids)
+        rows = int((hi - lo).sum())
+        width = sum(self.data[c].itemsize for c in cols) + sum(
+            self.nulls[c].itemsize for c in cols if c in self.nulls)
+        return dict(cols=len(cols), runs=len(lo), rows=rows,
+                    bytes=0 if len(lo) == 1 else rows * width)
+
+    def ctx_for(self, part_ids: Sequence[int],
+                columns: Optional[Sequence[str]] = None) -> RowContext:
+        """RowContext over the concatenation of the given partitions.
+
+        By default every column and null mask is gathered into fresh
+        arrays.  Given ``columns``, only those columns and their null
+        masks are read, slice by run of consecutive ids (``row_runs``):
+        one run is a read-only view of the table's arrays, copying
+        nothing (read it before the table's next DML); several runs are
+        one concatenation per column."""
+        if columns is None:
+            idx = np.concatenate(
+                [np.arange(self.part_bounds[p], self.part_bounds[p + 1])
+                 for p in part_ids]
+            ) if len(part_ids) else np.zeros(0, dtype=np.int64)
+            return RowContext(
+                self.columns,
+                {k: v[idx] for k, v in self.data.items()},
+                {k: v[idx] for k, v in self.nulls.items()},
+            )
+        lo, hi = self.row_runs(part_ids)
+
+        def take(v: np.ndarray) -> np.ndarray:
+            if len(lo) == 1:
+                view = v[lo[0]:hi[0]]
+                view.setflags(write=False)
+                return view
+            return np.concatenate([v[a:b] for a, b in zip(lo, hi)] or [v[:0]])
+
         return RowContext(
             self.columns,
-            {k: v[idx] for k, v in self.data.items()},
-            {k: v[idx] for k, v in self.nulls.items()},
+            {c: take(self.data[c]) for c in columns},
+            {c: take(self.nulls[c]) for c in columns if c in self.nulls},
         )
 
     def global_ctx(self) -> RowContext:

@@ -9,6 +9,7 @@ benchmark's own ``bench.tracing.Capture``.
 """
 
 import collections
+import contextlib
 import json
 import os
 import subprocess
@@ -22,6 +23,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from bench import tracing  # noqa: E402
+from repro import spans  # noqa: E402
 from repro.core import expr as E  # noqa: E402
 from repro.core.device_stats import DeviceStatsCache, tree_entry_for  # noqa: E402
 from repro.core.flow import JoinSpec, PruningPipeline, Query, TableScanSpec  # noqa: E402
@@ -122,6 +124,33 @@ def test_every_stage_of_a_mixed_batch_is_a_span_inside_run_batch(served):
     # one build-key pass, summary and probe per join query
     assert got["join.build_keys"] == got["join.summarize"] == 2
     assert got["join.apply"] == 2
+
+
+@pytest.mark.parametrize("windows", [[(0.2, 0.3)], [(0.0, 0.1), (0.9, 1.0)]],
+                         ids=["one_run", "two_runs"])
+def test_the_build_scan_span_says_what_it_gathered(monkeypatch, windows):
+    """``events`` (no null masks) as the build side: a window of the
+    clustered ``ts`` is one run of partitions, read as a view; two
+    windows are two runs, copied as ``rows x 8`` bytes per column."""
+    events, users = tables()
+    build = E.Or(tuple(_window(lo, hi) for lo, hi in windows))
+    q = Query(scans={"events": TableScanSpec(events, build),
+                     "users": TableScanSpec(users, E.col("age") >= 30)},
+              join=JoinSpec("events", "users", "user_id", "id"))
+    seen = []                  # each span's name and keyword metadata
+    monkeypatch.setattr(spans, "TraceAnnotation", lambda name, **meta: (
+        seen.append((name, meta)) or contextlib.nullcontext()))
+    report = PruningPipeline(filter_mode="host").run(q)
+    (meta,) = [m for n, m in seen if n == PREFIX + "join.build_keys"]
+    ids = report.scan_sets["events"].part_ids
+    rows = int(np.diff(events.part_bounds)[ids].sum())
+    assert meta["P"] == len(ids)
+    assert meta["cols"] == 2                       # ts and user_id
+    assert meta["runs"] == len(windows)
+    assert meta["rows"] == rows
+    assert meta["bytes"] == (0 if len(windows) == 1
+                             else rows * 8 * meta["cols"])
+    assert all(isinstance(v, int) for v in meta.values())
 
 
 def test_every_recorded_name_is_declared(served):
